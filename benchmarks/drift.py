@@ -35,7 +35,12 @@ import time
 
 import numpy as np
 
-from benchmarks.common import profile, save_report, workload
+from benchmarks.common import (
+    enable_compile_cache,
+    profile,
+    save_report,
+    workload,
+)
 from benchmarks.open_arrival import make_fleet_load
 from repro.core.controller import Objective
 from repro.core.controller_jax import fleet_planner_cache_size
@@ -211,4 +216,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,7 @@ import time
 import numpy as np
 
 from benchmarks.common import (
+    enable_compile_cache,
     exact_ann,
     save_report,
     update_bench_plan,
@@ -127,4 +128,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

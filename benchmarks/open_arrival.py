@@ -63,7 +63,12 @@ if _DEVICES and _DEVICES > 1 and \
 
 import numpy as np  # noqa: E402
 
-from benchmarks.common import exact_ann, save_report, workload  # noqa: E402
+from benchmarks.common import (  # noqa: E402
+    enable_compile_cache,
+    exact_ann,
+    save_report,
+    workload,
+)
 from repro.core.controller import Objective  # noqa: E402
 from repro.core.controller_jax import fleet_planner_cache_size  # noqa: E402
 from repro.core.events import run_events  # noqa: E402
@@ -241,4 +246,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -37,7 +37,12 @@ import time
 import numpy as np
 
 from benchmarks.admission import find_knee
-from benchmarks.common import exact_ann, save_report, workload
+from benchmarks.common import (
+    enable_compile_cache,
+    exact_ann,
+    save_report,
+    workload,
+)
 from benchmarks.open_arrival import make_fleet_load
 from repro.core.controller import Objective
 from repro.core.controller_jax import fleet_planner_cache_size
@@ -204,4 +209,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -25,6 +25,7 @@ import time
 import numpy as np
 
 from benchmarks.common import (
+    enable_compile_cache,
     exact_ann,
     save_report,
     update_bench_plan,
@@ -119,6 +120,7 @@ def run(batch: int = 256, iters: int = 50, workflows=WORKFLOWS,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

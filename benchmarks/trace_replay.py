@@ -65,7 +65,12 @@ if _DEVICES and max(_DEVICES) > 1 and \
 
 import numpy as np  # noqa: E402
 
-from benchmarks.common import exact_ann, save_report, workload  # noqa: E402
+from benchmarks.common import (  # noqa: E402
+    enable_compile_cache,
+    exact_ann,
+    save_report,
+    workload,
+)
 from benchmarks.open_arrival import make_fleet_load  # noqa: E402
 from repro.core.controller import Objective  # noqa: E402
 from repro.core.events import run_events  # noqa: E402
@@ -128,15 +133,15 @@ def _sharded_sweep(trie, ann, obj, reqs, arr, execu, kw, ckw,
     return {"n_requests": sn, "summary_identical": True, "per_devices": per}
 
 
-def replay(wf: str = "mathqa_4", n: int = 1_000_000, host_n: int = 20_000,
-           rate: float = 8.0, capacity: int = 32, epoch: int | None = None,
-           warm: bool = False, devices: tuple[int, ...] = ()):
-    """Run both lanes, differential-check the prefix, return the report.
+def deployment(wf: str = "mathqa_4", n: int = 1_000_000, rate: float = 8.0,
+               capacity: int = 32, seed: int = 0):
+    """The replayed deployment: ``wf``'s trie with exact annotations, a
+    max-accuracy objective at the 0.8 latency quantile, a trace-extended
+    Poisson stream of ``n`` requests at ``rate`` req/s, and the
+    load-aware policy with feasibility admission over ``capacity`` slots.
 
-    ``warm=True`` (the --tiny CI mode) times a SECOND run of each lane so
-    XLA/planner compiles are excluded; the full 1M run amortizes its
-    one-off compile into the measured wall instead of doubling the cost.
-    """
+    Returns (trie, ann, obj, requests, arrivals, executor, run_events
+    keyword arguments); ``seed`` shifts every draw."""
     trie, wl = workload(wf)
     ann = exact_ann(wf)
     execu = make_workload_executor(wl)
@@ -146,11 +151,24 @@ def replay(wf: str = "mathqa_4", n: int = 1_000_000, host_n: int = 20_000,
 
     # bootstrap-extend a short recorded trace to the cohort size (the
     # PR 6 trace_arrivals fix: gaps resampled from the empirical gaps)
-    base = poisson_arrivals(min(n, TRACE_SEED_LEN), rate, seed=1)
-    arr = trace_arrivals(base, n=n, seed=2)
-    reqs = np.random.default_rng(0).choice(wl.n_requests, n, replace=True)
+    base = poisson_arrivals(min(n, TRACE_SEED_LEN), rate, seed=seed + 1)
+    arr = trace_arrivals(base, n=n, seed=seed + 2)
+    reqs = np.random.default_rng(seed).choice(wl.n_requests, n, replace=True)
     kw = dict(capacity=capacity, policy="dynamic_load_aware",
               fleet_load=load, admission="feasibility")
+    return trie, ann, obj, reqs, arr, execu, kw
+
+
+def replay(wf: str = "mathqa_4", n: int = 1_000_000, host_n: int = 20_000,
+           rate: float = 8.0, capacity: int = 32, epoch: int | None = None,
+           warm: bool = False, devices: tuple[int, ...] = ()):
+    """Run both lanes, differential-check the prefix, return the report.
+
+    ``warm=True`` (the --tiny CI mode) times a SECOND run of each lane so
+    XLA/planner compiles are excluded; the full 1M run amortizes its
+    one-off compile into the measured wall instead of doubling the cost.
+    """
+    trie, ann, obj, reqs, arr, execu, kw = deployment(wf, n, rate, capacity)
     ckw = {} if epoch is None else {"epoch": epoch}
     host_n = min(host_n, n)
 
@@ -280,4 +298,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -326,7 +326,6 @@ def _sharded_scatter(mesh, n_cols: int):
     key = ("scatter", n_cols, _mesh_key(mesh))
     if key in _SHARDED_JITS:
         return _SHARDED_JITS[key]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.dist.sharding import LANE_AXIS, lane_spec
@@ -340,9 +339,9 @@ def _sharded_scatter(mesh, n_cols: int):
         return tuple(c.at[loc].set(v, mode="drop")
                      for c, v in zip(cols, vals))
 
-    fn = jax.jit(shard_map(scatter, mesh=mesh,
-                           in_specs=(lane, rep, rep),
-                           out_specs=(lane,) * n_cols, check_rep=False),
+    fn = jax.jit(jax.shard_map(scatter, mesh=mesh,
+                               in_specs=(lane, rep, rep),
+                               out_specs=(lane,) * n_cols, check_vma=False),
                  donate_argnums=(0,))
     _SHARDED_JITS[key] = fn
     return fn
@@ -356,7 +355,6 @@ def _sharded_plan(mesh, kind: str, variant: str):
     key = ("plan", kind, variant, _mesh_key(mesh))
     if key in _SHARDED_JITS:
         return _SHARDED_JITS[key]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.dist.sharding import lane_spec
@@ -369,10 +367,10 @@ def _sharded_plan(mesh, kind: str, variant: str):
         return _dispatch_plan(td, u, el, ec, delays, blocked, acc_floor,
                               cost_cap, lat_cap, kind=kind, variant=variant)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         plan, mesh=mesh,
         in_specs=(rep, lane, lane, lane, rep, rep, rep, rep, rep),
-        out_specs=(lane, lane), check_rep=False))
+        out_specs=(lane, lane), check_vma=False))
     _SHARDED_JITS[key] = fn
     return fn
 
@@ -389,7 +387,6 @@ def _sharded_plan_coupled(mesh, kind: str, variant: str):
     key = ("plan_coupled", kind, variant, _mesh_key(mesh))
     if key in _SHARDED_JITS:
         return _SHARDED_JITS[key]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.dist.sharding import LANE_AXIS, lane_spec
@@ -412,11 +409,11 @@ def _sharded_plan_coupled(mesh, kind: str, variant: str):
                                   variant=variant)
         return tgt, nxt, row
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         plan, mesh=mesh,
         in_specs=(rep, lane, lane, lane, lane, lane, rep,
                   rep, rep, rep, rep, rep, rep),
-        out_specs=(lane, lane, rep), check_rep=False))
+        out_specs=(lane, lane, rep), check_vma=False))
     _SHARDED_JITS[key] = fn
     return fn
 
@@ -703,7 +700,7 @@ def traced_fleet_plan(td: TrieDevice, prefixes, elapsed_lat, elapsed_cost,
     row broadcast across the capacity lanes, then the variant-dispatched
     kernel.  All operands must already carry the kernel's dtypes (int32
     prefixes, float32 elapsed/cost/delays) — inside an
-    ``jax.experimental.enable_x64`` scope the kernel arithmetic stays
+    ``jax.enable_x64`` scope the kernel arithmetic stays
     float32 end-to-end, bit-matching the host planner's programs.
 
     ``blocked`` is the (N,) float32 ``blocked_depth`` availability mask

@@ -30,7 +30,7 @@ Architecture (see docs/EVENT_ENGINE.md for the full design):
   class plus an unrolled K-way merge by (class weight, arrival seq)
   reproduces the host heap's pop order exactly.
 - **bit-compatibility**: the engine runs under a scoped
-  ``jax.experimental.enable_x64`` so all clock/work arithmetic is float64
+  ``jax.enable_x64`` so all clock/work arithmetic is float64
   with the same op order as the host's numpy (the planner kernel stays
   explicitly float32 on both paths).  The differential oracle
   (`tests/test_oracle_differential.py`, ``engine="compiled"`` lane) pins
@@ -1292,16 +1292,15 @@ def _build_step(cfg: _EngineConfig):
         # each device walks only its residue class of needy lanes
         # (collective-free inner while_loop — device-varying trip counts
         # are legal there), and one psum per replan round rebroadcasts the
-        # merged plans.  check_rep=False because jax cannot prove the
+        # merged plans.  check_vma=False because jax cannot prove the
         # psum output replicated through the surrounding loops.
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as PSpec
 
         from repro.dist.sharding import lane_mesh
         rep = PSpec()
-        step = shard_map(step, mesh=lane_mesh(cfg.n_shards),
-                         in_specs=(rep, rep, rep), out_specs=rep,
-                         check_rep=False)
+        step = jax.shard_map(step, mesh=lane_mesh(cfg.n_shards),
+                             in_specs=(rep, rep, rep), out_specs=rep,
+                             check_vma=False)
     jitted = jax.jit(step, donate_argnums=(0,))
     _ENGINE_CACHE[cfg] = jitted
     return jitted
@@ -1685,11 +1684,10 @@ def run_events_compiled(
         paused_cap=B if fault_outages else (C if priorities else 0))
     step = _build_step(cfg)
 
-    from jax.experimental import enable_x64
+    import jax
+    import jax.numpy as jnp
 
-    with enable_x64():
-        import jax.numpy as jnp
-
+    with jax.enable_x64(True):
         dg_obj = Objective("min_cost", acc_floor=-1.0,
                            cost_cap=obj.cost_cap, lat_cap=plan_obj.lat_cap)
         cn = {

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,11 +70,11 @@ def pipeline_forward(stages, x, stage_body, *, mesh, axis: str = "pipe"):
         # only the last stage wrote anything; psum replicates the result
         return jax.lax.psum(out, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stages), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stages, x)

@@ -32,13 +32,25 @@ from repro.kernels.xla_trie import fleet_plan_blocked
 _NAIVE_ATTN_ELEMS = 512 * 512
 _NAIVE_SSD_LEN = 256
 
-_INTERPRET = True  # no TPU in this container; flipped by launch scripts
+
+def _interpret() -> bool:
+    """Pallas mode for the default backend: compiled on TPU, interpret mode
+    on CPU (the test lane).  Any other backend raises rather than silently
+    running the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU; the "
+        f"default JAX backend is {backend!r}")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _attention_pallas(q, k, v, causal, window):
     return _pallas_flash(q, k, v, causal=causal, window=window,
-                         interpret=_INTERPRET)
+                         interpret=_interpret())
 
 
 def _attention_fwd(q, k, v, causal, window):
@@ -78,7 +90,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0,
     buffer); short caches use the naive oracle."""
     if use_pallas:
         return _pallas_decode(q, k_cache, v_cache, cache_len, window=window,
-                              interpret=_INTERPRET)
+                              interpret=_interpret())
     # NOTE: a blocked K-scan variant (decode_attention_xla) was tried and
     # REFUTED for the sharded dry-run: dynamic block slices over the
     # sequence-sharded cache force per-block all-gathers (435x collective
@@ -90,7 +102,8 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _ssd_pallas(x, dt, A, Bm, Cm, chunk):
-    return _pallas_ssd(x, dt, A, Bm, Cm, chunk=chunk, interpret=_INTERPRET)
+    return _pallas_ssd(x, dt, A, Bm, Cm, chunk=chunk,
+                       interpret=_interpret())
 
 
 def _ssd_fwd(x, dt, A, Bm, Cm, chunk):
@@ -129,7 +142,7 @@ def ssd_decode_step(x, dt, A, Bm, Cm, state):
 
 def rms_norm(x, scale, eps=1e-6, *, use_pallas=False):
     if use_pallas:
-        return _pallas_rmsnorm(x, scale, eps, interpret=_INTERPRET)
+        return _pallas_rmsnorm(x, scale, eps, interpret=_interpret())
     return ref.rms_norm(x, scale, eps)
 
 
@@ -173,7 +186,7 @@ def trie_plan(terminal, depth, acc, cost, lat, subtree_size, path_models,
             terminal, depth, acc, cost, lat, subtree_size, path_models,
             path_counts, engine_of_model, prefixes, elapsed_lat,
             elapsed_cost, engine_delays, acc_floor, cost_cap, lat_cap,
-            kind=kind, blocked_depth=blocked_depth, interpret=_INTERPRET)
+            kind=kind, blocked_depth=blocked_depth, interpret=_interpret())
     if variant == "fused":
         return fleet_plan_blocked(
             terminal, depth, acc, cost, lat, subtree_size, path_models,

@@ -87,62 +87,70 @@ def _tile_lexmin_update(carry, idx0, term_t, depth_t, acc_t, cost_t, lat_t,
                         delay_u, thr, pmd, cap_eff, floor_eff, *, kind):
     """Merge one node tile into the per-request running lexicographic minima.
 
-    ``carry`` = (bk1, bk2, bk3, bidx, bnxt), each (B,): the best key triple
-    seen so far, its global node index, and the first-step model id gathered
-    when that node became the incumbent.  Pure jnp — executed identically by
-    the Pallas kernel body and the XLA mirror's fori-loop, so the two paths
-    cannot drift.
+    Everything is 2-D so that Mosaic can lay it out: nodes run along the
+    lane (last) axis and requests along the sublane axis.  Node columns
+    (``term_t`` ... ``lat_t``, ``bd_t``) are (1, T) rows, ``counts_t`` and
+    ``pm_t`` are the node-minor (M, T) and (Dmax, T) transposes, request
+    statistics (``lo`` ... ``thr``) are (B, 1) columns and ``pmd`` is
+    (B, M).  ``carry`` = (bk1, bk2, bk3, bidx, bnxt), each (B, 1): the best
+    key triple seen so far, its global node index, and the first-step
+    model id gathered when that node became the incumbent.  Pure jnp —
+    executed identically by the Pallas kernel body and the XLA mirror's
+    fori-loop, so the two paths cannot drift.
 
-    ``bd_t`` is the availability mask as a node column (``blocked_depth``:
+    ``bd_t`` is the availability mask as a node row (``blocked_depth``:
     1 + deepest dead-engine stage position on the node's root path, 0 when
     clean); a candidate survives only if ``bd_t <= depth[u]`` — no *new*
     stage may sit on a down engine.  All-zeros means every engine is up.
     """
     bk1, bk2, bk3, bidx, bnxt = carry
-    tile = term_t.shape[0]
+    tile = term_t.shape[1]
     gidx = idx0 + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)  # (1, T)
+    # HIGHEST: the TPU's default f32 matmul rounds operands to bf16, which
+    # would move the delays off the float64 host search's values
+    hi_prec = jax.lax.Precision.HIGHEST
 
     # cumulative engine delay for every (request, node) pair in the tile:
     # path-multiplicity counts x per-model delay rows — one MXU contraction
-    delay_bt = jnp.dot(pmd, counts_t.T,
+    delay_bt = jnp.dot(pmd, counts_t, precision=hi_prec,
                        preferred_element_type=jnp.float32)         # (B, T)
-    d_lat = (lat_t[None, :] - lat_u[:, None]) + (delay_bt - delay_u[:, None])
-    d_cost = cost_t[None, :] - cost_u[:, None]
-    feas = (term_t[None, :] > 0.5)
-    feas &= (gidx >= lo[:, None]) & (gidx < hi[:, None])
-    feas &= bd_t[None, :] <= du[:, None].astype(jnp.float32)
-    feas &= d_lat <= thr[:, None]
-    feas &= cost_t[None, :] <= cap_eff
+    d_lat = (lat_t - lat_u) + (delay_bt - delay_u)
+    d_cost = cost_t - cost_u
+    feas = (gidx >= lo) & (gidx < hi)
+    feas &= term_t > 0.5
+    feas &= bd_t <= du.astype(jnp.float32)
+    feas &= d_lat <= thr
+    feas &= cost_t <= cap_eff
     if kind == "min_cost":
-        feas &= acc_t[None, :] >= floor_eff
-        k1v, k2v, k3v = d_cost, d_lat, jnp.broadcast_to(depth_t[None, :],
+        feas &= acc_t >= floor_eff
+        k1v, k2v, k3v = d_cost, d_lat, jnp.broadcast_to(depth_t,
                                                         d_lat.shape)
     else:
-        k1v = jnp.broadcast_to(-acc_t[None, :], d_lat.shape)
+        k1v = jnp.broadcast_to(-acc_t, d_lat.shape)
         k2v, k3v = d_cost, d_lat
 
     # tile-local exact lexicographic argmin (narrowing over the tile only)
     k1 = jnp.where(feas, k1v, BIG)
-    m1 = k1.min(axis=1)
-    c2 = feas & (k1 <= m1[:, None])
+    m1 = k1.min(axis=1, keepdims=True)
+    c2 = feas & (k1 <= m1)
     k2 = jnp.where(c2, k2v, BIG)
-    m2 = k2.min(axis=1)
-    c3 = c2 & (k2 <= m2[:, None])
+    m2 = k2.min(axis=1, keepdims=True)
+    c3 = c2 & (k2 <= m2)
     k3 = jnp.where(c3, k3v, BIG)
-    m3 = k3.min(axis=1)
-    c4 = c3 & (k3 <= m3[:, None])
-    li = jnp.where(c4, gidx, BIG_IDX).min(axis=1).astype(jnp.int32)  # (B,)
+    m3 = k3.min(axis=1, keepdims=True)
+    c4 = c3 & (k3 <= m3)
+    li = jnp.where(c4, gidx, BIG_IDX).min(axis=1, keepdims=True)     # (B, 1)
 
     # first step of the tile winner, gathered from the RESIDENT pm tile:
-    # pm_du[b, t] = pm_t[t, du_b] via a one-hot depth contraction, then the
+    # pm_du[b, t] = pm_t[du_b, t] via a one-hot depth contraction, then the
     # winner row via a one-hot index mask — no dynamic gather needed.
-    dmax = pm_t.shape[1]
+    dmax = pm_t.shape[0]
     dio = jax.lax.broadcasted_iota(jnp.int32, (1, dmax), 1)          # (1, D)
-    onehot_du = (dio == du[:, None]).astype(jnp.float32)             # (B, D)
-    pm_du = jnp.dot(onehot_du, pm_t.T,
+    onehot_du = (dio == du).astype(jnp.float32)                      # (B, D)
+    pm_du = jnp.dot(onehot_du, pm_t, precision=hi_prec,
                     preferred_element_type=jnp.float32)              # (B, T)
-    win = c4 & (gidx == li[:, None])
-    nxt_t = jnp.sum(jnp.where(win, pm_du, 0.0), axis=1)              # (B,)
+    win = c4 & (gidx == li)
+    nxt_t = jnp.sum(jnp.where(win, pm_du, 0.0), axis=1, keepdims=True)
 
     # cross-tile lexicographic merge (strict: earlier tiles win exact ties,
     # preserving the lowest-node-index tie-break)
@@ -177,26 +185,25 @@ def _trie_plan_kernel(scal_ref, term_ref, depth_ref, acc_ref, cost_ref,
     n = pl.program_id(0)
     b = pl.program_id(1)
     tb = lo_ref.shape[0]
-    sl = pl.ds(b * tb, tb)
+    best = (bk1_ref, bk2_ref, bk3_ref, bidx_ref, bnxt_ref)
 
+    # the running minima of lane block b live in row b of the
+    # (lane blocks, tb, 1) scratch: a dynamic index on the untiled leading
+    # axis, so no store needs a tile-aligned offset
     @pl.when(n == 0)
     def _():
-        bk1_ref[sl] = jnp.full((tb,), BIG, jnp.float32)
-        bk2_ref[sl] = jnp.full((tb,), BIG, jnp.float32)
-        bk3_ref[sl] = jnp.full((tb,), BIG, jnp.float32)
-        bidx_ref[sl] = jnp.full((tb,), BIG_IDX, jnp.int32)
-        bnxt_ref[sl] = jnp.full((tb,), -1.0, jnp.float32)
+        for ref, init in zip(best, (BIG, BIG, BIG, BIG_IDX, -1.0)):
+            ref[b] = jnp.full((tb, 1), init, ref.dtype)
 
-    carry = (bk1_ref[sl], bk2_ref[sl], bk3_ref[sl], bidx_ref[sl],
-             bnxt_ref[sl])
     carry = _tile_lexmin_update(
-        carry, n * block_nodes,
+        tuple(ref[b] for ref in best), n * block_nodes,
         term_ref[...], depth_ref[...], acc_ref[...], cost_ref[...],
         lat_ref[...], counts_ref[...], pm_ref[...], bd_ref[...],
         lo_ref[...], hi_ref[...], du_ref[...], latu_ref[...],
         costu_ref[...], delayu_ref[...], thr_ref[...], pmd_ref[...],
         scal_ref[0], scal_ref[1], kind=kind)
-    bk1_ref[sl], bk2_ref[sl], bk3_ref[sl], bidx_ref[sl], bnxt_ref[sl] = carry
+    for ref, val in zip(best, carry):
+        ref[b] = val
     # running best is written every visit; the last node tile's write is the
     # final answer (output blocks are indexed by the batch lane only)
     tgt_ref[...], nxt_ref[...] = finalize(carry, lo_ref[...])
@@ -208,6 +215,36 @@ def _pad_to(x, size, fill):
         return x
     widths = ((0, pad),) + ((0, 0),) * (x.ndim - 1)
     return jnp.pad(x, widths, constant_values=fill)
+
+
+def node_rows(terminal, depth, acc, cost, lat, path_counts, path_models,
+              blocked_depth, n_pad):
+    """Trie SoA in the tile layout, padded to ``n_pad`` nodes: (1, n_pad)
+    rows of terminal/depth/acc/cost/lat, the (M, n_pad) and (Dmax, n_pad)
+    transposes of path_counts/path_models, and the blocked_depth row.
+    Padded nodes are non-terminal (never feasible)."""
+    f32 = jnp.float32
+
+    def row(a, fill):
+        return _pad_to(a.astype(f32), n_pad, fill)[None, :]
+
+    return [
+        row(terminal, 0.0), row(depth, 0.0), row(acc, 0.0), row(cost, 0.0),
+        row(lat, 0.0),
+        _pad_to(path_counts.astype(f32), n_pad, 0.0).T,
+        _pad_to(path_models.astype(f32), n_pad, -1.0).T,
+        row(blocked_depth, 0.0),
+    ]
+
+
+def lane_columns(lo, hi, du, lat_u, cost_u, delay_u, thr):
+    """Per-request statistics as (B, 1) columns: int32 interval bounds and
+    prefix depth, float32 prefix annotations and latency threshold."""
+    i32, f32 = jnp.int32, jnp.float32
+    return [lo.astype(i32)[:, None], hi.astype(i32)[:, None],
+            du.astype(i32)[:, None], lat_u.astype(f32)[:, None],
+            cost_u.astype(f32)[:, None], delay_u.astype(f32)[:, None],
+            thr.astype(f32)[:, None]]
 
 
 def trie_plan_pallas(
@@ -233,7 +270,9 @@ def trie_plan_pallas(
         blocked_depth = jnp.zeros_like(terminal)
     n = terminal.shape[0]
     bsz = prefixes.shape[0]
-    block_nodes = min(block_nodes, max(pl.cdiv(n, 8) * 8, 8))
+    # node tiles run along the 128-wide lane axis; padded nodes are
+    # non-terminal, so they are never feasible
+    block_nodes = min(block_nodes, pl.cdiv(n, 128) * 128)
     n_pad = pl.cdiv(n, block_nodes) * block_nodes
     tb = min(block_lanes, max(pl.cdiv(bsz, 8) * 8, 8))
     b_pad = pl.cdiv(bsz, tb) * tb
@@ -244,54 +283,35 @@ def trie_plan_pallas(
                       lat_cap, cost_cap, acc_floor)
 
     f32 = jnp.float32
-    node_ops = [
-        (_pad_to(terminal.astype(f32), n_pad, 0.0), (block_nodes,)),
-        (_pad_to(depth.astype(f32), n_pad, 0.0), (block_nodes,)),
-        (_pad_to(acc.astype(f32), n_pad, 0.0), (block_nodes,)),
-        (_pad_to(cost.astype(f32), n_pad, 0.0), (block_nodes,)),
-        (_pad_to(lat.astype(f32), n_pad, 0.0), (block_nodes,)),
-        (_pad_to(path_counts.astype(f32), n_pad, 0.0),
-         (block_nodes, path_counts.shape[1])),
-        (_pad_to(path_models.astype(f32), n_pad, -1.0),
-         (block_nodes, path_models.shape[1])),
-        (_pad_to(blocked_depth.astype(f32), n_pad, 0.0), (block_nodes,)),
-    ]
+    node_ops = node_rows(terminal, depth, acc, cost, lat, path_counts,
+                         path_models, blocked_depth, n_pad)
     # padded lanes get hi=0 (empty interval -> infeasible -> tgt -1)
-    lane_ops = [
-        (_pad_to(lo.astype(jnp.int32), b_pad, 0), jnp.int32),
-        (_pad_to(hi.astype(jnp.int32), b_pad, 0), jnp.int32),
-        (_pad_to(du, b_pad, 0), jnp.int32),
-        (_pad_to(lat_u.astype(f32), b_pad, 0.0), f32),
-        (_pad_to(cost_u.astype(f32), b_pad, 0.0), f32),
-        (_pad_to(delay_u.astype(f32), b_pad, 0.0), f32),
-        (_pad_to(thr.astype(f32), b_pad, 0.0), f32),
-    ]
+    lane_ops = [_pad_to(c, b_pad, 0) for c in lane_columns(
+        lo, hi, du, lat_u, cost_u, delay_u, thr)]
     pmd_p = _pad_to(pmd, b_pad, 0.0)
     scal = jnp.stack([jnp.asarray(cap_eff, f32), jnp.asarray(floor_eff, f32)])
 
     grid = (n_pad // block_nodes, b_pad // tb)
-    in_specs = [pl.BlockSpec((2,), lambda i, j: (0,))]
-    in_specs += [
-        pl.BlockSpec(shape, lambda i, j, _nd=len(shape): (i,) + (0,) * (_nd - 1))
-        for _, shape in node_ops
-    ]
-    in_specs += [pl.BlockSpec((tb,), lambda i, j: (j,))
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    in_specs += [pl.BlockSpec((a.shape[0], block_nodes), lambda i, j: (0, i))
+                 for a in node_ops]
+    in_specs += [pl.BlockSpec((tb, 1), lambda i, j: (j, 0))
                  for _ in lane_ops]
     in_specs += [pl.BlockSpec((tb, pmd_p.shape[1]), lambda i, j: (j, 0))]
-    scratch = [pltpu.VMEM((b_pad,), f32), pltpu.VMEM((b_pad,), f32),
-               pltpu.VMEM((b_pad,), f32), pltpu.VMEM((b_pad,), jnp.int32),
-               pltpu.VMEM((b_pad,), f32)]
+    n_lane_blocks = b_pad // tb
+    scratch = [pltpu.VMEM((n_lane_blocks, tb, 1), dt)
+               for dt in (f32, f32, f32, jnp.int32, f32)]
 
     tgt, nxt = pl.pallas_call(
         functools.partial(_trie_plan_kernel, kind=kind,
                           block_nodes=block_nodes),
         grid=grid,
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((tb,), lambda i, j: (j,)),
-                   pl.BlockSpec((tb,), lambda i, j: (j,))),
-        out_shape=(jax.ShapeDtypeStruct((b_pad,), jnp.int32),
-                   jax.ShapeDtypeStruct((b_pad,), jnp.int32)),
+        out_specs=(pl.BlockSpec((tb, 1), lambda i, j: (j, 0)),
+                   pl.BlockSpec((tb, 1), lambda i, j: (j, 0))),
+        out_shape=(jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((b_pad, 1), jnp.int32)),
         scratch_shapes=scratch,
         interpret=interpret,
-    )(scal, *[a for a, _ in node_ops], *[a for a, _ in lane_ops], pmd_p)
-    return tgt[:bsz], nxt[:bsz]
+    )(scal, *node_ops, *lane_ops, pmd_p)
+    return tgt[:bsz, 0], nxt[:bsz, 0]

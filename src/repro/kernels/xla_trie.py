@@ -16,9 +16,10 @@ from repro.kernels.trie_plan import (
     BIG,
     BIG_IDX,
     DEFAULT_BLOCK_NODES,
-    _pad_to,
     _tile_lexmin_update,
     finalize,
+    lane_columns,
+    node_rows,
     request_stats,
 )
 
@@ -56,37 +57,28 @@ def fleet_plan_blocked(
                       lat_cap, cost_cap, acc_floor)
 
     f32 = jnp.float32
-    term_p = _pad_to(terminal.astype(f32), n_pad, 0.0)
-    depth_p = _pad_to(depth.astype(f32), n_pad, 0.0)
-    acc_p = _pad_to(acc.astype(f32), n_pad, 0.0)
-    cost_p = _pad_to(cost.astype(f32), n_pad, 0.0)
-    lat_p = _pad_to(lat.astype(f32), n_pad, 0.0)
-    counts_p = _pad_to(path_counts.astype(f32), n_pad, 0.0)
-    pm_p = _pad_to(path_models.astype(f32), n_pad, -1.0)
-    bd_p = _pad_to(blocked_depth.astype(f32), n_pad, 0.0)
+    rows = node_rows(terminal, depth, acc, cost, lat, path_counts,
+                     path_models, blocked_depth, n_pad)
+    cols = lane_columns(lo, hi, du, lat_u, cost_u, delay_u, thr)
 
     carry0 = (
-        jnp.full((bsz,), BIG, f32),
-        jnp.full((bsz,), BIG, f32),
-        jnp.full((bsz,), BIG, f32),
-        jnp.full((bsz,), BIG_IDX, jnp.int32),
-        jnp.full((bsz,), -1.0, f32),
+        jnp.full((bsz, 1), BIG, f32),
+        jnp.full((bsz, 1), BIG, f32),
+        jnp.full((bsz, 1), BIG, f32),
+        jnp.full((bsz, 1), BIG_IDX, jnp.int32),
+        jnp.full((bsz, 1), -1.0, f32),
     )
 
     def body(i, carry):
         s = i * block_nodes
-
-        def tile(a):
-            return jax.lax.dynamic_slice_in_dim(a, s, block_nodes)
-
-        return _tile_lexmin_update(
-            carry, s, tile(term_p), tile(depth_p), tile(acc_p),
-            tile(cost_p), tile(lat_p), tile(counts_p), tile(pm_p),
-            tile(bd_p), lo, hi, du, lat_u, cost_u, delay_u, thr, pmd,
-            cap_eff, floor_eff, kind=kind)
+        tiles = [jax.lax.dynamic_slice_in_dim(a, s, block_nodes, axis=1)
+                 for a in rows]
+        return _tile_lexmin_update(carry, s, *tiles, *cols, pmd, cap_eff,
+                                   floor_eff, kind=kind)
 
     if n_tiles == 1:
         carry = body(0, carry0)
     else:
         carry = jax.lax.fori_loop(0, n_tiles, body, carry0)
-    return finalize(carry, lo)
+    tgt, nxt = finalize(carry, cols[0])
+    return tgt[:, 0], nxt[:, 0]

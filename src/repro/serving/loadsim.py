@@ -745,7 +745,7 @@ class FleetEngineSim:
 # jnp mirrors of the FleetEngineSim drain arithmetic, for use INSIDE the
 # jitted epoch step of `repro.core.events_compiled`.  Each function is the
 # exact IEEE image of the numpy method it mirrors (same op order, float64
-# under `jax.experimental.enable_x64`), so the compiled engine's virtual
+# under `jax.enable_x64`), so the compiled engine's virtual
 # clock is bit-compatible with the host calendar: the differential-oracle
 # sweep pins this.  jax is imported lazily so this module stays importable
 # (numpy-only) for hosts that never touch the compiled path.

@@ -81,7 +81,8 @@ from repro.dist.sharding import sharding_tree, batch_specs
 from repro.train import OptConfig, TrainConfig, make_train_step
 from repro.data import DataConfig, MarkovLMData
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 # compare loss + gradient norm: elementwise post-Adam params are
 # ill-conditioned (update ~ sign(g) where g ~ 0, so f32 reduction-order
 # drift between shardings flips individual elements)

@@ -16,7 +16,8 @@ sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.pipeline import pipeline_forward, split_stages
 
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = jax.make_mesh((4,), ("pipe",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 L, d, mb, n_micro, S = 8, 16, 2, 6, 4
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (L, d, d)) * 0.2

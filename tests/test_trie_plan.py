@@ -243,3 +243,18 @@ def test_resident_planner_detects_donated_buffer_invalidation():
     tgt1, nxt1 = planner.replan(row)
     np.testing.assert_array_equal(tgt0, tgt1)
     np.testing.assert_array_equal(nxt0, nxt1)
+
+
+def test_pallas_mode_follows_backend(monkeypatch):
+    """Pallas runs compiled on TPU, interpreted on the CPU test backend,
+    and refuses any other backend instead of silently interpreting."""
+    import jax
+
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()
