@@ -74,27 +74,3 @@ def save_report(name: str, payload) -> str:
     with open(path, "w") as f:
         json.dump(payload, f, indent=1, default=float)
     return path
-
-
-def update_bench_plan(section: str, payload) -> str:
-    """Merge one section into the machine-readable planner-perf trajectory
-    file ``reports/bench/BENCH_plan.json``.
-
-    `benchmarks/table3_overhead.py` writes the per-replan variant sweep,
-    `benchmarks/fleet_throughput.py` the full fleet-step sweep; CI uploads
-    the result as a workflow artifact so planner perf is comparable across
-    PRs.  Read-modify-write so standalone bench runs and `benchmarks.run`
-    both land in the same file."""
-    os.makedirs(REPORT_DIR, exist_ok=True)
-    path = os.path.join(REPORT_DIR, "BENCH_plan.json")
-    data = {"schema": "bench_plan/v1"}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                data.update(json.load(f))
-        except (OSError, ValueError):
-            pass
-    data[section] = payload
-    with open(path, "w") as f:
-        json.dump(data, f, indent=1, default=float)
-    return path

@@ -13,7 +13,6 @@ the steady-state comparison (a serving fleet compiles once per cohort
 shape, then replans millions of times).  Both paths report the MIN over
 repeats: the container has no isolated cores and XLA dispatch has a heavy
 scheduling tail, so the minimum is the comparable noise-floor statistic.
-Variant rows also land in ``reports/bench/BENCH_plan.json``.
 
     PYTHONPATH=src python benchmarks/fleet_throughput.py [--tiny]
 """
@@ -28,7 +27,6 @@ from benchmarks.common import (
     enable_compile_cache,
     exact_ann,
     save_report,
-    update_bench_plan,
     workload,
 )
 from repro.core.controller import Objective
@@ -96,7 +94,6 @@ def run(wf: str = "nl2sql_8", batches=FULL_BATCHES, repeats: int = 7,
             })
     elapsed = time.perf_counter() - t_total
     save_report("fleet_throughput", rows)
-    update_bench_plan("fleet_step", {"workflow": wf, "rows": rows})
     best = max(r["replan_speedup"] for r in rows)
     return {
         "name": "fleet_throughput",

@@ -2,10 +2,7 @@
 
 Prints ``name,us_per_call,derived`` CSV.  Detailed rows are written to
 reports/bench/*.json; each module is also runnable standalone for full
-output (``python -m benchmarks.fig7_frontier`` etc.).  The planner-perf
-sweeps (table3_overhead, fleet_throughput) additionally merge their
-variant rows into the machine-readable ``reports/bench/BENCH_plan.json``
-trajectory file, which CI uploads as a workflow artifact.
+output (``python -m benchmarks.fig7_frontier`` etc.).
 """
 from __future__ import annotations
 
@@ -54,14 +51,6 @@ def main() -> None:
             failures += 1
             print(f"{name},nan,FAILED:{type(e).__name__}:{e}")
             traceback.print_exc()
-
-    import os
-
-    from benchmarks.common import REPORT_DIR
-    plan_path = os.path.join(REPORT_DIR, "BENCH_plan.json")
-    if os.path.exists(plan_path):
-        print(f"# BENCH_plan.json -> {os.path.abspath(plan_path)}",
-              file=sys.stderr)
     if failures:
         raise SystemExit(1)
 

@@ -14,9 +14,7 @@ scales to fleets:
   compiled on TPU the tile pass maps 1:1 onto VMEM-resident trie tiles).
 
 At the largest preset trie the fused planner must beat the dense program —
-the benchmark asserts it (min-over-iters, full mode only), and every
-variant's numbers land in ``reports/bench/BENCH_plan.json`` so the perf
-trajectory is comparable across PRs.
+the benchmark asserts it (min-over-iters, full mode only).
 """
 from __future__ import annotations
 
@@ -28,7 +26,6 @@ from benchmarks.common import (
     enable_compile_cache,
     exact_ann,
     save_report,
-    update_bench_plan,
     workload,
 )
 from repro.core.controller import Objective, select_path
@@ -91,7 +88,6 @@ def run(batch: int = 256, iters: int = 50, workflows=WORKFLOWS,
             })
     elapsed = time.perf_counter() - total_t0
     save_report("table3_overhead", rows)
-    update_bench_plan("per_replan", {"batch": batch, "rows": rows})
 
     # the fused planner must beat the pre-fusion program where it matters:
     # the largest preset trie (full runs; --tiny sweeps one small preset)

@@ -142,6 +142,11 @@ class EventStats:
     timeouts: int = 0               # stages aborted by the timeout model
     fault_retries: int = 0          # backoff retries scheduled after aborts
     failed: int = 0                 # requests terminally failed ("failed")
+    # compiled engine only (repro.core.events_compiled), zero on the host
+    # loop: width-1 planner sweeps, step dispatches, host seconds per phase
+    sweeps: int = 0
+    epochs: int = 0
+    host_s: dict = dataclasses.field(default_factory=dict)
     replan_s: list = dataclasses.field(default_factory=list)
     planned_per_replan: list = dataclasses.field(default_factory=list)
     peak_occupancy: dict = dataclasses.field(default_factory=dict)

@@ -61,10 +61,23 @@ baseline lane of `benchmarks/chaos.py`), and faults combined with
 predictive/cost-aware admission (their displaced-work forecast inflation
 and the downgrade lane's host-side min-cost search cannot see the
 availability mask).
+
+Tracing (docs/EVENT_ENGINE.md, "Spans, scopes and counters"): every
+event phase of the step runs under one of four named scopes (`SCOPES`),
+which only annotate the compiled program's HLO metadata, and every call
+opens six host spans ``vinelm.build`` ... ``vinelm.drain``, recorded
+while a `jax.profiler` session is open.  The host phases' wall times, the
+count of width-1 planner sweeps and of step dispatches are counted in
+every call (`EventStats.host_s`/``sweeps``/``epochs`` and the streamed
+summary).  `engine_scope_maps` joins a device trace's operation names to
+the scopes.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import re
+import time
 import warnings
 from typing import Callable
 
@@ -92,6 +105,7 @@ from repro.core.events import _DEFAULT_CAPACITY, EventStats, _explore_tables
 from repro.core.runtime import ExecutionResult, StageExecutor
 from repro.core.streaming import QuantileSketch, welford_merge
 from repro.core.trie import Trie, TrieAnnotations
+from repro.kernels.ops import PLAN_SCOPE
 
 # outcome codes inside the traced state (host strings on the way out)
 _OC_SERVED, _OC_REJECTED, _OC_SHED, _OC_FAILED = 0, 1, 2, 3
@@ -102,6 +116,13 @@ _DONE_TOL = 1e-9     # FleetEngineSim._DONE_TOL
 _SLO_TOL = 1e-9      # run_events' final SLO check tolerance
 
 DEFAULT_EPOCH = 1024  # arrivals per jitted step (throughput knob, not math)
+
+# the step's named scopes: the event loop with its float64 clock and
+# calendar drain; queue, admission, preemption and the replan cycle's
+# loop; the replan round and dispatch outside the sweeps; the width-1
+# sweeps themselves (`repro.kernels.ops.trie_plan`)
+SCOPES = ("vinelm/clock", "vinelm/admit", "vinelm/dispatch", PLAN_SCOPE)
+_CLOCK, _ADMIT, _DISPATCH, _ = SCOPES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,6 +166,9 @@ class _EngineConfig:
 
 
 _ENGINE_CACHE: dict[_EngineConfig, Callable] = {}
+# (config, operand structure, operand avals) -> the step program, for
+# every specialization that ran, so `engine_scope_maps` can lower it again
+_ENGINE_CALLS: dict[tuple, Callable] = {}
 
 
 def compiled_engine_cache_size() -> int:
@@ -160,6 +184,96 @@ def compiled_engine_cache_size() -> int:
         except Exception:
             return -1
     return total
+
+
+def _call_signature(cfg: _EngineConfig, st, cn) -> tuple:
+    import jax
+
+    leaves, tree = jax.tree.flatten((st, cn))
+    return cfg, tree, tuple(jax.typeof(x) for x in leaves)
+
+
+def engine_scope_maps() -> list[dict[str, str]]:
+    """One map per compiled engine program this process has called: HLO
+    instruction name (``while.404``, as a device trace names the
+    operation) -> its ``op_name`` scope path
+    (``jit(step)/while/body/vinelm/dispatch/...``).
+
+    Built only on request: each program is lowered again from its
+    operands' shapes and compiled (a hit in JAX's persistent compilation
+    cache where one is on), and its text parsed by `hlo_scope_map`.  Call
+    it after the calls it describes."""
+    import jax
+
+    maps = []
+    for (_, tree, avals), step in _ENGINE_CALLS.items():
+        args = jax.tree.unflatten(tree, [
+            jax.ShapeDtypeStruct(a.shape, a.dtype, weak_type=a.weak_type)
+            for a in avals])
+        with jax.enable_x64(True):
+            text = step.lower(*args, 0.0).compile().as_text()
+        maps.append(hlo_scope_map(text))
+    return maps
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?(\S+) \(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLEE = re.compile(r"\b(?:calls|body|condition|to_apply|true_computation"
+                         r"|false_computation)=%?([\w.\-]+)")
+_HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def hlo_scope_map(text: str) -> dict[str, str]:
+    """Instruction name -> ``op_name`` of a compiled HLO module's text.
+
+    An instruction without metadata (the loop-carried tuples and copies
+    XLA adds) takes the ``op_name`` of the instruction that calls its
+    computation, the while, conditional or fusion it runs inside."""
+    own, comp_of, caller = {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        comp_of[name] = comp
+        op = _HLO_OP_NAME.search(line)
+        if op:
+            own[name] = op.group(1)
+        callees = _HLO_CALLEE.findall(line)
+        for branches in _HLO_BRANCHES.findall(line):
+            callees += [c.strip().lstrip("%") for c in branches.split(",")]
+        for c in callees:
+            caller[c] = name
+    out = {}
+    for name in comp_of:
+        seen, cur = set(), name
+        while cur not in own and cur not in seen:
+            seen.add(cur)
+            cur = caller.get(comp_of[cur])
+            if cur is None:
+                break
+        if cur is not None and cur in own:
+            out[name] = own[cur]
+    return out
+
+
+@contextlib.contextmanager
+def _host_span(host_s: dict, phase: str, **meta):
+    """One host phase of a call: the profiler span ``vinelm.<phase>``
+    (written only while a profiler session is open) around the block,
+    whose wall time is always added to ``host_s[phase]``."""
+    import jax
+
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"vinelm.{phase}", **meta) as span:
+        yield span
+    host_s[phase] = host_s.get(phase, 0.0) + time.perf_counter() - t0
 
 
 def _build_step(cfg: _EngineConfig):
@@ -998,6 +1112,13 @@ def _build_step(cfg: _EngineConfig):
         else:
             bd = None
         need = st["snd"]
+        # the round's width-1 sweeps, counted on the replicated need mask
+        # (each needy lane is swept once, by the one device owning it, and
+        # a downgraded lane once more, for its min-cost plan)
+        sweeps = jnp.sum(jnp.where(need, 1, 0))
+        if pol.max_occupancy is not None and pol.downgrade:
+            sweeps = sweeps + jnp.sum(jnp.where(need & st["sdg"], 1, 0))
+        st["swp"] = st["swp"] + sweeps
         if cfg.n_shards > 1:
             # Sharded control plane: every device keeps the full replicated
             # bookkeeping (the event loop is sequential and globally
@@ -1233,6 +1354,8 @@ def _build_step(cfg: _EngineConfig):
         return tn
 
     def event_body(st, cn):
+        # runs in the clock's scope (see `step`); the admission and
+        # dispatch phases open their own
         t = st["tn"]
         st = {**st, "ev": st["ev"] + 1, "snd": jnp.zeros(C, bool)}
         if cfg.ps:
@@ -1245,12 +1368,14 @@ def _build_step(cfg: _EngineConfig):
             st = {**st, "jrm": jrm, "tl": tl}
         st = phase_completions(st, cn, t)
         if cfg.fault_outages:
-            st = phase_faults(st, cn, t)
+            with jax.named_scope(_ADMIT):
+                st = phase_faults(st, cn, t)
         st = phase_deadline_sheds(st, cn, t)
-        st = phase_arrivals(st, cn, t)
-        st = phase_queue_rejections(st, cn, t)
-        if cfg.fault_failures:
-            st = phase_retry_release(st, cn, t)
+        with jax.named_scope(_ADMIT):
+            st = phase_arrivals(st, cn, t)
+            st = phase_queue_rejections(st, cn, t)
+            if cfg.fault_failures:
+                st = phase_retry_release(st, cn, t)
 
         # 3-5 cycle: preempt -> admit/resume -> replan -> dispatch,
         # repeated while freed slots can absorb queued arrivals
@@ -1263,9 +1388,10 @@ def _build_step(cfg: _EngineConfig):
             s = phase_preempt(s, cn, t)
             s = phase_admit(s, cn, t)
             need_any = s["snd"].any()
-            s = lax.cond(need_any,
-                         lambda ss: phase_replan_dispatch(ss, cn, t),
-                         lambda ss: ss, s)
+            with jax.named_scope(_DISPATCH):
+                s = lax.cond(need_any,
+                             lambda ss: phase_replan_dispatch(ss, cn, t),
+                             lambda ss: ss, s)
             valid, _, _, _ = merged_head(s, cn)
             again = jnp.where(
                 need_any,
@@ -1273,15 +1399,21 @@ def _build_step(cfg: _EngineConfig):
                 any_preemptable(s, cn))
             return s, again
 
-        st, _ = lax.while_loop(cyc_cond, cyc_body,
-                               (st, jnp.asarray(True)))
+        # the cycle's loop is admission's: its sequencing counts there
+        with jax.named_scope(_ADMIT):
+            st, _ = lax.while_loop(cyc_cond, cyc_body,
+                                   (st, jnp.asarray(True)))
         return {**st, "tn": next_event_time(st, cn)}
 
     def step(st, cn, t_hi):
         def cond(s):
             return jnp.isfinite(s["tn"]) & (s["tn"] <= t_hi)
 
-        return lax.while_loop(cond, lambda s: event_body(s, cn), st)
+        # the event loop is the virtual clock's: its sequencing (the gaps
+        # between the body's operations, the loop-carried copies) counts
+        # in the clock's scope, the phases that open their own aside
+        with jax.named_scope(_CLOCK):
+            return lax.while_loop(cond, lambda s: event_body(s, cn), st)
 
     if cfg.n_shards > 1:
         # SPMD wrapper: every operand and result is REPLICATED (empty
@@ -1485,369 +1617,397 @@ def run_events_compiled(
                 "host loop (compiled=False)")
     requests = np.asarray(requests)
     B = int(requests.shape[0])
-    if arrivals is None:
-        arrivals = np.zeros(B, dtype=np.float64)
-    else:
-        arrivals = np.asarray(arrivals, dtype=np.float64)
-        if arrivals.shape != (B,):
-            raise ValueError(f"arrivals shape {arrivals.shape} != ({B},)")
-        if B and (not np.all(np.isfinite(arrivals)) or arrivals.min() < 0):
-            raise ValueError("arrivals must be finite and non-negative")
-    if capacity is None:
-        capacity = B if arrivals.size == 0 or arrivals.max() == 0.0 \
-            else min(B, _DEFAULT_CAPACITY)
-    C = int(capacity)
-    if B and C < 1:
-        raise ValueError("capacity must be >= 1")
+    host_s: dict[str, float] = {}
+    with _host_span(host_s, "build", requests=B) as span:
+        if arrivals is None:
+            arrivals = np.zeros(B, dtype=np.float64)
+        else:
+            arrivals = np.asarray(arrivals, dtype=np.float64)
+            if arrivals.shape != (B,):
+                raise ValueError(f"arrivals shape {arrivals.shape} != ({B},)")
+            if B and (not np.all(np.isfinite(arrivals)) or arrivals.min() < 0):
+                raise ValueError("arrivals must be finite and non-negative")
+        if capacity is None:
+            capacity = B if arrivals.size == 0 or arrivals.max() == 0.0 \
+                else min(B, _DEFAULT_CAPACITY)
+        C = int(capacity)
+        if B and C < 1:
+            raise ValueError("capacity must be >= 1")
 
-    priorities = class_specs is not None
-    if not priorities and classes is not None:
-        raise ValueError("classes requires class_specs (the SLOClass table "
-                         "the indices point into)")
-    base_cap = obj.lat_cap if obj.lat_cap is not None else np.inf
-    if priorities:
-        specs = tuple(class_specs)
-        if not specs:
-            raise ValueError("class_specs must be a non-empty sequence of "
-                             "SLO classes")
-        cls_idx = (np.zeros(B, dtype=np.int64) if classes is None
-                   else np.asarray(classes, dtype=np.int64))
-        if cls_idx.shape != (B,):
-            raise ValueError(f"classes shape {cls_idx.shape} != ({B},)")
-        if B and (cls_idx.min() < 0 or cls_idx.max() >= len(specs)):
-            raise ValueError(
-                f"classes must index the {len(specs)} class_specs entries")
-        cap_cls = np.array([c.deadline_s if c.deadline_s is not None
-                            else base_cap for c in specs], dtype=np.float64)
-        w_cls = np.array([c.weight for c in specs], dtype=np.float64)
-        cap_req = cap_cls[cls_idx]
-        weight_req = w_cls[cls_idx]
-        K = len(specs)
-    else:
-        cls_idx = np.zeros(B, dtype=np.int64)
-        cap_req = np.full(B, base_cap)
-        weight_req = np.ones(B)
-        w_cls = np.ones(1)
-        K = 1
-
-    stats = EventStats(capacity=C, policy=pol.name,
-                       outcome=[SERVED] * B,
-                       arrival_t=arrivals.copy(),
-                       admit_t=np.zeros(B, dtype=np.float64),
-                       done_t=np.zeros(B, dtype=np.float64),
-                       class_of=cls_idx.copy() if priorities else None,
-                       preempt_count=np.zeros(B, dtype=np.int64))
-    if B == 0:
-        return ([], stats) if not stream else (
-            _empty_summary(stats), stats)
-
-    td = TrieDevice.build(trie, ann, restrict_nodes)
-    swaps: list[tuple[float, TrieDevice]] = []
-    if annotation_schedule:
-        sched = sorted(annotation_schedule, key=lambda sa: float(sa[0]))
-        for i, (ts, swap_ann) in enumerate(sched):
-            ts = float(ts)
-            if not np.isfinite(ts) or ts < 0:
+        priorities = class_specs is not None
+        if not priorities and classes is not None:
+            raise ValueError("classes requires class_specs (the SLOClass table "
+                             "the indices point into)")
+        base_cap = obj.lat_cap if obj.lat_cap is not None else np.inf
+        if priorities:
+            specs = tuple(class_specs)
+            if not specs:
+                raise ValueError("class_specs must be a non-empty sequence of "
+                                 "SLO classes")
+            cls_idx = (np.zeros(B, dtype=np.int64) if classes is None
+                       else np.asarray(classes, dtype=np.int64))
+            if cls_idx.shape != (B,):
+                raise ValueError(f"classes shape {cls_idx.shape} != ({B},)")
+            if B and (cls_idx.min() < 0 or cls_idx.max() >= len(specs)):
                 raise ValueError(
-                    f"annotation_schedule swap time {ts!r} must be finite "
-                    "and non-negative")
-            swap_td = TrieDevice.build(trie, swap_ann, restrict_nodes)
-            swap_td.version = i + 1
-            swaps.append((ts, swap_td))
-    lat_shift = np.zeros(B)
-    eff_cap = None
-    if priorities:
-        finite = cap_req[np.isfinite(cap_req)]
-        eff_cap = float(finite.max()) if finite.size else None
-        if eff_cap is not None:
-            lat_shift = np.where(np.isfinite(cap_req),
-                                 eff_cap - cap_req, -np.inf)
-            # same float32 elapsed-shift resolution caveat as the host
-            # loop (see run_events): warn when the deadline spread makes
-            # the quantization material for the tightest class
-            step = float(np.spacing(np.float32(eff_cap)))
-            if step > 1e-3 * float(finite.min()):
-                warnings.warn(
-                    f"class deadline spread ({finite.min():.3g}s .. "
-                    f"{eff_cap:.3g}s) exceeds float32 elapsed-shift "
-                    f"resolution ({step:.3g}s at the largest cap): the "
-                    "planner's feasibility may lag the deadline "
-                    "bookkeeping by up to that much for tight classes",
-                    stacklevel=2)
-    plan_obj = obj if eff_cap is None \
-        else dataclasses.replace(obj, lat_cap=eff_cap)
-    engines = trie_engines(trie.template)
-    E = len(engines)
-    M = trie.template.n_models
-    max_depth = trie.template.max_depth
-    load_aware = policy == "dynamic_load_aware"
+                    f"classes must index the {len(specs)} class_specs entries")
+            cap_cls = np.array([c.deadline_s if c.deadline_s is not None
+                                else base_cap for c in specs], dtype=np.float64)
+            w_cls = np.array([c.weight for c in specs], dtype=np.float64)
+            cap_req = cap_cls[cls_idx]
+            weight_req = w_cls[cls_idx]
+            K = len(specs)
+        else:
+            cls_idx = np.zeros(B, dtype=np.int64)
+            cap_req = np.full(B, base_cap)
+            weight_req = np.ones(B)
+            w_cls = np.ones(1)
+            K = 1
 
-    term_mask = trie.terminal.copy()
-    if restrict_nodes is not None:
-        keep = np.zeros(trie.n_nodes, dtype=bool)
-        keep[restrict_nodes] = True
-        term_mask &= keep
-    pol.bind(trie, ann, obj, term_mask)
-    tpol = traced_admission(pol)  # re-distill with bound min_path_lat
-    explore_model = _explore_tables(trie, term_mask, B, explore)
-    deadline_sheds = pol.shed_on_deadline and bool(
-        np.isfinite(cap_req).any())
+        stats = EventStats(capacity=C, policy=pol.name,
+                           outcome=[SERVED] * B,
+                           arrival_t=arrivals.copy(),
+                           admit_t=np.zeros(B, dtype=np.float64),
+                           done_t=np.zeros(B, dtype=np.float64),
+                           class_of=cls_idx.copy() if priorities else None,
+                           preempt_count=np.zeros(B, dtype=np.int64),
+                           host_s=host_s)
+        if B == 0:
+            return ([], stats) if not stream else (
+                _empty_summary(stats), stats)
 
-    # load coupling: the traced calendar needs the concrete
-    # EngineLoadModel parameters, not a duck-typed slowdown callable
-    conc = np.full(E, np.inf)
-    ms = np.ones(E)
-    hasm = np.zeros(E, dtype=bool)
-    tokens = work_model is not None
-    ps = tokens or (load_aware and fleet_load is not None)
-    if tokens:
-        # token calendar (ISSUE 10): the decode-step curve coefficients
-        # become (E,) traced operands; conc stays inf (shape source only
-        # — the rate curve never reads it).  tk1 = decode_step_s(1) is
-        # precomputed here so the trace and the host share one rounding.
-        tkw = np.zeros(E)
-        tkv = np.zeros(E)
-        tkf = np.zeros(E)
-        tkc = np.ones(E)
-        tk1 = np.ones(E)
-        for j, e in enumerate(engines):
-            m = work_model.engines.get(e)
-            if m is None:
-                raise ValueError(
-                    f"work_model has no token model for engine {e!r}: the "
-                    "token calendar needs every trie engine's decode curve")
-            tkw[j] = float(m.t_weights_s)
-            tkv[j] = float(m.t_kv_s)
-            tkf[j] = float(m.t_flop_s)
-            tkc[j] = float(m.kv_capacity)
-            tk1[j] = max(float(m.t_weights_s) + float(m.t_kv_s),
-                         float(m.t_flop_s))
-            ms[j] = float(work_model.mean_service_s.get(e, 1.0))
-            hasm[j] = True
-    elif ps:
-        from repro.serving.loadsim import EngineLoadModel, FleetLoadModel
-        if not isinstance(fleet_load, FleetLoadModel) or not all(
-                isinstance(m, EngineLoadModel)
-                for m in fleet_load.engines.values()):
-            raise NotImplementedError(
-                "compiled event engine supports FleetLoadModel with "
-                "EngineLoadModel entries; use the host loop for duck-typed "
-                "load models")
-        for j, e in enumerate(engines):
-            m = fleet_load.engines.get(e)
-            if m is not None:
-                conc[j] = float(m.concurrency)
-                ms[j] = float(fleet_load.mean_service_s.get(e, 1.0))
+        td = TrieDevice.build(trie, ann, restrict_nodes)
+        swaps: list[tuple[float, TrieDevice]] = []
+        if annotation_schedule:
+            sched = sorted(annotation_schedule, key=lambda sa: float(sa[0]))
+            for i, (ts, swap_ann) in enumerate(sched):
+                ts = float(ts)
+                if not np.isfinite(ts) or ts < 0:
+                    raise ValueError(
+                        f"annotation_schedule swap time {ts!r} must be finite "
+                        "and non-negative")
+                swap_td = TrieDevice.build(trie, swap_ann, restrict_nodes)
+                swap_td.version = i + 1
+                swaps.append((ts, swap_td))
+        lat_shift = np.zeros(B)
+        eff_cap = None
+        if priorities:
+            finite = cap_req[np.isfinite(cap_req)]
+            eff_cap = float(finite.max()) if finite.size else None
+            if eff_cap is not None:
+                lat_shift = np.where(np.isfinite(cap_req),
+                                     eff_cap - cap_req, -np.inf)
+                # same float32 elapsed-shift resolution caveat as the host
+                # loop (see run_events): warn when the deadline spread makes
+                # the quantization material for the tightest class
+                step = float(np.spacing(np.float32(eff_cap)))
+                if step > 1e-3 * float(finite.min()):
+                    warnings.warn(
+                        f"class deadline spread ({finite.min():.3g}s .. "
+                        f"{eff_cap:.3g}s) exceeds float32 elapsed-shift "
+                        f"resolution ({step:.3g}s at the largest cap): the "
+                        "planner's feasibility may lag the deadline "
+                        "bookkeeping by up to that much for tight classes",
+                        stacklevel=2)
+        plan_obj = obj if eff_cap is None \
+            else dataclasses.replace(obj, lat_cap=eff_cap)
+        engines = trie_engines(trie.template)
+        E = len(engines)
+        M = trie.template.n_models
+        max_depth = trie.template.max_depth
+        load_aware = policy == "dynamic_load_aware"
+
+        term_mask = trie.terminal.copy()
+        if restrict_nodes is not None:
+            keep = np.zeros(trie.n_nodes, dtype=bool)
+            keep[restrict_nodes] = True
+            term_mask &= keep
+        pol.bind(trie, ann, obj, term_mask)
+        tpol = traced_admission(pol)  # re-distill with bound min_path_lat
+        explore_model = _explore_tables(trie, term_mask, B, explore)
+        deadline_sheds = pol.shed_on_deadline and bool(
+            np.isfinite(cap_req).any())
+
+        # load coupling: the traced calendar needs the concrete
+        # EngineLoadModel parameters, not a duck-typed slowdown callable
+        conc = np.full(E, np.inf)
+        ms = np.ones(E)
+        hasm = np.zeros(E, dtype=bool)
+        tokens = work_model is not None
+        ps = tokens or (load_aware and fleet_load is not None)
+        if tokens:
+            # token calendar: the decode-step curve coefficients
+            # become (E,) traced operands; conc stays inf (shape source only
+            # — the rate curve never reads it).  tk1 = decode_step_s(1) is
+            # precomputed here so the trace and the host share one rounding.
+            tkw = np.zeros(E)
+            tkv = np.zeros(E)
+            tkf = np.zeros(E)
+            tkc = np.ones(E)
+            tk1 = np.ones(E)
+            for j, e in enumerate(engines):
+                m = work_model.engines.get(e)
+                if m is None:
+                    raise ValueError(
+                        f"work_model has no token model for engine {e!r}: the "
+                        "token calendar needs every trie engine's decode curve")
+                tkw[j] = float(m.t_weights_s)
+                tkv[j] = float(m.t_kv_s)
+                tkf[j] = float(m.t_flop_s)
+                tkc[j] = float(m.kv_capacity)
+                tk1[j] = max(float(m.t_weights_s) + float(m.t_kv_s),
+                             float(m.t_flop_s))
+                ms[j] = float(work_model.mean_service_s.get(e, 1.0))
                 hasm[j] = True
+        elif ps:
+            from repro.serving.loadsim import EngineLoadModel, FleetLoadModel
+            if not isinstance(fleet_load, FleetLoadModel) or not all(
+                    isinstance(m, EngineLoadModel)
+                    for m in fleet_load.engines.values()):
+                raise NotImplementedError(
+                    "compiled event engine supports FleetLoadModel with "
+                    "EngineLoadModel entries; use the host loop for duck-typed "
+                    "load models")
+            for j, e in enumerate(engines):
+                m = fleet_load.engines.get(e)
+                if m is not None:
+                    conc[j] = float(m.concurrency)
+                    ms[j] = float(fleet_load.mean_service_s.get(e, 1.0))
+                    hasm[j] = True
 
-    order = np.argsort(arrivals, kind="stable")
-    seq_of = np.empty(B, dtype=np.int64)
-    seq_of[order] = np.arange(B)
-    members = np.full((K, B), -1, dtype=np.int32)
-    cls_ord = cls_idx[order].astype(np.int32)
-    for k in range(K):
-        mem_k = order[cls_ord == k]
-        members[k, :mem_k.size] = mem_k
+        order = np.argsort(arrivals, kind="stable")
+        seq_of = np.empty(B, dtype=np.int64)
+        seq_of[order] = np.arange(B)
+        members = np.full((K, B), -1, dtype=np.int32)
+        cls_ord = cls_idx[order].astype(np.int32)
+        for k in range(K):
+            mem_k = order[cls_ord == k]
+            members[k, :mem_k.size] = mem_k
 
-    # only (depth, model) pairs some trie node can dispatch get probed
-    probe = np.zeros((max_depth + 1, M), dtype=bool)
-    node_depth = trie.depth.astype(np.int64)
-    has_child = trie.child >= 0  # (n_nodes, M)
-    np.logical_or.at(probe, node_depth, has_child)
-    tab_s, tab_c, tab_l, row = _tabulate_executor(
-        executor, requests, probe, t_start, work_model=work_model,
-        engines=engines,
-        engine_of_model=np.asarray(td.engine_of_model, dtype=np.int64))
-    best_acc, min_cost = _subtree_reductions(trie, ann, term_mask)
+        # only (depth, model) pairs some trie node can dispatch get probed
+        probe = np.zeros((max_depth + 1, M), dtype=bool)
+        node_depth = trie.depth.astype(np.int64)
+        has_child = trie.child >= 0  # (n_nodes, M)
+        np.logical_or.at(probe, node_depth, has_child)
+        best_acc, min_cost = _subtree_reductions(trie, ann, term_mask)
 
-    n_shards = 1 if devices is None else int(devices)
-    if n_shards < 1:
-        raise ValueError(f"devices must be >= 1, got {devices}")
-    if n_shards > 1:
-        from repro.dist.sharding import lane_mesh
-        lane_mesh(n_shards)  # availability check: clear error + CPU recipe
+        n_shards = 1 if devices is None else int(devices)
+        if n_shards < 1:
+            raise ValueError(f"devices must be >= 1, got {devices}")
+        if n_shards > 1:
+            from repro.dist.sharding import lane_mesh
+            lane_mesh(n_shards)  # availability check: clear error + CPU recipe
 
-    sketch = QuantileSketch.log_spaced()
-    cfg = _EngineConfig(
-        capacity=C, n_classes=K, n_engines=E, n_models=M,
-        max_depth=max_depth, priorities=priorities, preempt=bool(preempt),
-        ps=ps, load_aware=load_aware, tokens=tokens,
-        deadline_sheds=deadline_sheds,
-        pol=tpol, kind=obj.kind, kind_dg="min_cost",
-        variant=_resolve_variant(plan_variant), n_bins=sketch.n_bins,
-        n_shards=n_shards, explore=explore_model is not None,
-        fault_outages=fault_outages, fault_failures=fault_failures,
-        max_retries=int(faults.max_retries) if faults is not None else 0,
-        # outage victims can stack past C across repeated outages, so the
-        # paused buffer is sized B under fault injection (shapes already
-        # carry B-sized columns — no retrace cost)
-        paused_cap=B if fault_outages else (C if priorities else 0))
-    step = _build_step(cfg)
+        sketch = QuantileSketch.log_spaced()
+        cfg = _EngineConfig(
+            capacity=C, n_classes=K, n_engines=E, n_models=M,
+            max_depth=max_depth, priorities=priorities, preempt=bool(preempt),
+            ps=ps, load_aware=load_aware, tokens=tokens,
+            deadline_sheds=deadline_sheds,
+            pol=tpol, kind=obj.kind, kind_dg="min_cost",
+            variant=_resolve_variant(plan_variant), n_bins=sketch.n_bins,
+            n_shards=n_shards, explore=explore_model is not None,
+            fault_outages=fault_outages, fault_failures=fault_failures,
+            max_retries=int(faults.max_retries) if faults is not None else 0,
+            # outage victims can stack past C across repeated outages, so the
+            # paused buffer is sized B under fault injection (shapes already
+            # carry B-sized columns — no retrace cost)
+            paused_cap=B if fault_outages else (C if priorities else 0))
+        step = _build_step(cfg)
+        span.set_metadata(nodes=int(trie.n_nodes),
+                          dmax=int(td.path_models.shape[1]),
+                          models=M, engines=E)
+    with _host_span(host_s, "tabulate", requests=B):
+        tab_s, tab_c, tab_l, row = _tabulate_executor(
+            executor, requests, probe, t_start, work_model=work_model,
+            engines=engines,
+            engine_of_model=np.asarray(td.engine_of_model, dtype=np.int64))
 
     import jax
     import jax.numpy as jnp
 
     with jax.enable_x64(True):
-        dg_obj = Objective("min_cost", acc_floor=-1.0,
-                           cost_cap=obj.cost_cap, lat_cap=plan_obj.lat_cap)
-        cn = {
-            "td": td,
-            "sc": objective_scalars(plan_obj),
-            "scdg": objective_scalars(dg_obj),
-            "arr": jnp.asarray(arrivals),
-            "arrs": jnp.asarray(arrivals[order]),
-            "cap": jnp.asarray(cap_req),
-            "wreq": jnp.asarray(weight_req),
-            "shift": jnp.asarray(lat_shift),
-            "seq": jnp.asarray(seq_of),
-            "cls": jnp.asarray(cls_idx.astype(np.int32)),
-            "clsord": jnp.asarray(cls_ord),
-            "members": jnp.asarray(members),
-            "wcls": jnp.asarray(w_cls),
-            "child": jnp.asarray(trie.child.astype(np.int32)),
-            "depth": jnp.asarray(trie.depth.astype(np.int32)),
-            "eom": jnp.asarray(
-                np.asarray(td.engine_of_model).astype(np.int32)),
-            "row": jnp.asarray(row),
-            "tabs": jnp.asarray(tab_s),
-            "tabc": jnp.asarray(tab_c),
-            "tabl": jnp.asarray(tab_l),
-            "conc": jnp.asarray(conc),
-            "ms": jnp.asarray(ms),
-            "hasm": jnp.asarray(hasm),
-            "bacc": jnp.asarray(best_acc),
-            "mcost": jnp.asarray(min_cost),
-            "edges": jnp.asarray(sketch.edges),
-        }
-        if tokens:
-            # added only under the token calendar so legacy configs keep
-            # their exact operand pytree (and compiled-program cache keys)
-            cn["tkw"] = jnp.asarray(tkw)
-            cn["tkv"] = jnp.asarray(tkv)
-            cn["tkf"] = jnp.asarray(tkf)
-            cn["tkc"] = jnp.asarray(tkc)
-            cn["tk1"] = jnp.asarray(tk1)
-        if explore_model is not None:
-            cn["xpm"] = jnp.asarray(explore_model)
-        if fault_outages:
-            # transition columns, padded with one sentinel row so the
-            # traced cursor clip reads (inf, engine 0, up) past the end
-            fev = faults.events(engines)
-            cn["ftt"] = jnp.asarray(
-                np.array([t for t, _, _ in fev] + [np.inf]))
-            cn["fte"] = jnp.asarray(
-                np.array([ei for _, ei, _ in fev] + [0], dtype=np.int32))
-            cn["ftu"] = jnp.asarray(
-                np.array([up for _, _, up in fev] + [True], dtype=bool))
-        if fault_failures:
-            cn["fdr"] = jnp.asarray(faults.failure_draws(B, max_depth))
-            cn["fbo"] = jnp.asarray(
-                np.array([faults.backoff(a)
-                          for a in range(int(faults.max_retries) + 1)]))
-        st = _init_state(jnp, cfg, B, arrivals[order])
-
-        arrs = arrivals[order]
-        chunk = max(int(epoch), 1)
-        pos = 0
-        si = 0
-        while True:
-            pos2 = min(pos + chunk, B)
-            t_arr_hi = np.inf if pos2 >= B else float(arrs[pos2 - 1])
-            if si < len(swaps) and swaps[si][0] < t_arr_hi:
-                # annotation-version swap: run the current program up to
-                # the swap time (events at t <= t_swap stay under the old
-                # annotations — same rule as the host loop), then
-                # substitute the new TrieDevice operand.  t_hi and the
-                # annotation columns are traced operands, so the swap
-                # compiles ZERO new programs.
-                st = step(st, cn, float(swaps[si][0]))
-                cn = {**cn, "td": swaps[si][1]}
-                si += 1
-                continue
-            st = step(st, cn, t_arr_hi)
-            pos = pos2
-            if pos >= B:
-                # arrivals exhausted: one final unbounded epoch drains
-                # every remaining completion/deadline event
-                break
-        stats.annotation_swaps = si
-        n_done = int(st["don"])
-        if n_done != B:
-            raise RuntimeError(
-                f"compiled event loop stalled with work outstanding "
-                f"({n_done}/{B} requests terminal)")
-
-        stats.events = int(st["ev"])
-        stats.replans = int(st["rp"])
-        stats.admitted = int(st["adm"])
-        stats.rejected = int(st["rej"])
-        stats.shed = int(st["shd"])
-        stats.downgraded = int(st["dgc"])
-        stats.preemptions = int(st["pre"])
-        stats.resumed = int(st["res"])
-        stats.explored = int(st["xpc"])
-        if fault_outages:
-            stats.engine_outages = int(st["foc"])
-            stats.engine_recoveries = int(st["frc"])
-            stats.checkpointed = int(st["fck"])
-        if fault_failures:
-            stats.stage_failures = int(st["fsc"])
-            stats.fault_retries = int(st["frt"])
-        if fault_outages or fault_failures:
-            stats.failed = int(st["ffc"])
-        stats.peak_occupancy = {
-            e: int(v) for e, v in zip(engines, np.asarray(st["po"]))}
-        sketch.merge_counts(np.asarray(st["hist"]), edges=sketch.edges)
-        if stream:
-            # constant-memory path: per-request columns stay on device and
-            # are never materialized as host-side python lists; the summary
-            # is O(1) scalars + the fixed-size quantile histogram (carried
-            # under "sketch" so shard drains merge exactly)
-            summary = {
-                "n_requests": B,
-                "events": stats.events,
-                "replans": stats.replans,
-                "served": B - stats.rejected - stats.shed - stats.failed,
-                "succeeded": int(jnp.sum(st["rsc"])),
-                "rejected": stats.rejected,
-                "shed": stats.shed,
-                "failed": stats.failed,
-                "slo_violations": int(st["slo"]),
-                "latency": _wf(st["lw"]),
-                "cost": _wf(st["cw"]),
-                "latency_p50": sketch.quantile(0.5),
-                "latency_p95": sketch.quantile(0.95),
-                "latency_p99": sketch.quantile(0.99),
-                "sketch": sketch.state(),
+        with _host_span(host_s, "upload", requests=B):
+            dg_obj = Objective("min_cost", acc_floor=-1.0,
+                               cost_cap=obj.cost_cap, lat_cap=plan_obj.lat_cap)
+            cn = {
+                "td": td,
+                "sc": objective_scalars(plan_obj),
+                "scdg": objective_scalars(dg_obj),
+                "arr": jnp.asarray(arrivals),
+                "arrs": jnp.asarray(arrivals[order]),
+                "cap": jnp.asarray(cap_req),
+                "wreq": jnp.asarray(weight_req),
+                "shift": jnp.asarray(lat_shift),
+                "seq": jnp.asarray(seq_of),
+                "cls": jnp.asarray(cls_idx.astype(np.int32)),
+                "clsord": jnp.asarray(cls_ord),
+                "members": jnp.asarray(members),
+                "wcls": jnp.asarray(w_cls),
+                "child": jnp.asarray(trie.child.astype(np.int32)),
+                "depth": jnp.asarray(trie.depth.astype(np.int32)),
+                "eom": jnp.asarray(
+                    np.asarray(td.engine_of_model).astype(np.int32)),
+                "row": jnp.asarray(row),
+                "tabs": jnp.asarray(tab_s),
+                "tabc": jnp.asarray(tab_c),
+                "tabl": jnp.asarray(tab_l),
+                "conc": jnp.asarray(conc),
+                "ms": jnp.asarray(ms),
+                "hasm": jnp.asarray(hasm),
+                "bacc": jnp.asarray(best_acc),
+                "mcost": jnp.asarray(min_cost),
+                "edges": jnp.asarray(sketch.edges),
             }
-            stats.preempt_count = np.zeros(0, dtype=np.int64)
-            stats.outcome = []
-            return summary, stats
+            if tokens:
+                # added only under the token calendar so legacy configs keep
+                # their exact operand pytree (and compiled-program cache keys)
+                cn["tkw"] = jnp.asarray(tkw)
+                cn["tkv"] = jnp.asarray(tkv)
+                cn["tkf"] = jnp.asarray(tkf)
+                cn["tkc"] = jnp.asarray(tkc)
+                cn["tk1"] = jnp.asarray(tk1)
+            if explore_model is not None:
+                cn["xpm"] = jnp.asarray(explore_model)
+            if fault_outages:
+                # transition columns, padded with one sentinel row so the
+                # traced cursor clip reads (inf, engine 0, up) past the end
+                fev = faults.events(engines)
+                cn["ftt"] = jnp.asarray(
+                    np.array([t for t, _, _ in fev] + [np.inf]))
+                cn["fte"] = jnp.asarray(
+                    np.array([ei for _, ei, _ in fev] + [0], dtype=np.int32))
+                cn["ftu"] = jnp.asarray(
+                    np.array([up for _, _, up in fev] + [True], dtype=bool))
+            if fault_failures:
+                cn["fdr"] = jnp.asarray(faults.failure_draws(B, max_depth))
+                cn["fbo"] = jnp.asarray(
+                    np.array([faults.backoff(a)
+                              for a in range(int(faults.max_retries) + 1)]))
+            st = _init_state(jnp, cfg, B, arrivals[order])
 
-        roc = np.asarray(st["roc"])
-        rsc = np.asarray(st["rsc"])
-        rct = np.asarray(st["rct"])
-        ru = np.asarray(st["ru"])
-        stats.done_t = np.asarray(st["rdn"]).copy()
-        stats.admit_t = np.asarray(st["rad"]).copy()
-        stats.preempt_count = np.asarray(st["rpc"]).astype(np.int64)
-        stats.outcome = [_OUTCOMES[int(o)] for o in roc]
-        results = []
-        for i in range(B):
-            lat = float(stats.done_t[i] - stats.arrival_t[i])
-            slo = bool(np.isfinite(cap_req[i])) and lat > cap_req[i] + _SLO_TOL
-            mods = trie.path(int(ru[i]))
-            results.append(ExecutionResult(
-                success=bool(rsc[i]),
-                total_cost=float(rct[i]),
-                total_lat=lat,
-                models=mods,
-                n_stages=len(mods),
-                replan_overhead_s=0.0,
-                slo_violated=slo,
-                outcome=stats.outcome[i],
-            ))
-        return results, stats
+        with _host_span(host_s, "enqueue", requests=B):
+            signature = _call_signature(cfg, st, cn)
+            epochs = 0
+            arrs = arrivals[order]
+            chunk = max(int(epoch), 1)
+            pos = 0
+            si = 0
+            while True:
+                pos2 = min(pos + chunk, B)
+                t_arr_hi = np.inf if pos2 >= B else float(arrs[pos2 - 1])
+                if si < len(swaps) and swaps[si][0] < t_arr_hi:
+                    # annotation-version swap: run the current program up to
+                    # the swap time (events at t <= t_swap stay under the old
+                    # annotations — same rule as the host loop), then
+                    # substitute the new TrieDevice operand.  t_hi and the
+                    # annotation columns are traced operands, so the swap
+                    # compiles ZERO new programs.
+                    st = step(st, cn, float(swaps[si][0]))
+                    epochs += 1
+                    cn = {**cn, "td": swaps[si][1]}
+                    si += 1
+                    continue
+                st = step(st, cn, t_arr_hi)
+                epochs += 1
+                pos = pos2
+                if pos >= B:
+                    # arrivals exhausted: one final unbounded epoch drains
+                    # every remaining completion/deadline event
+                    break
+            _ENGINE_CALLS.setdefault(signature, step)
+        stats.annotation_swaps = si
+        stats.epochs = epochs
+        # every readback below would wait for the device; waiting here
+        # first tells the device's time from the drain's
+        with _host_span(host_s, "wait", requests=B):
+            jax.block_until_ready(st)
+        with _host_span(host_s, "drain", requests=B) as span:
+            n_done = int(st["don"])
+            if n_done != B:
+                raise RuntimeError(
+                    f"compiled event loop stalled with work outstanding "
+                    f"({n_done}/{B} requests terminal)")
+
+            stats.events = int(st["ev"])
+            stats.replans = int(st["rp"])
+            stats.sweeps = int(st["swp"])
+            stats.admitted = int(st["adm"])
+            stats.rejected = int(st["rej"])
+            stats.shed = int(st["shd"])
+            stats.downgraded = int(st["dgc"])
+            stats.preemptions = int(st["pre"])
+            stats.resumed = int(st["res"])
+            stats.explored = int(st["xpc"])
+            if fault_outages:
+                stats.engine_outages = int(st["foc"])
+                stats.engine_recoveries = int(st["frc"])
+                stats.checkpointed = int(st["fck"])
+            if fault_failures:
+                stats.stage_failures = int(st["fsc"])
+                stats.fault_retries = int(st["frt"])
+            if fault_outages or fault_failures:
+                stats.failed = int(st["ffc"])
+            stats.peak_occupancy = {
+                e: int(v) for e, v in zip(engines, np.asarray(st["po"]))}
+            sketch.merge_counts(np.asarray(st["hist"]), edges=sketch.edges)
+            span.set_metadata(events=stats.events, sweeps=stats.sweeps,
+                              epochs=stats.epochs)
+            if stream:
+                # constant-memory path: per-request columns stay on device and
+                # are never materialized as host-side python lists; the summary
+                # is O(1) scalars + the fixed-size quantile histogram (carried
+                # under "sketch" so shard drains merge exactly)
+                summary = {
+                    "n_requests": B,
+                    "events": stats.events,
+                    "replans": stats.replans,
+                    "served": B - stats.rejected - stats.shed - stats.failed,
+                    "succeeded": int(jnp.sum(st["rsc"])),
+                    "rejected": stats.rejected,
+                    "shed": stats.shed,
+                    "failed": stats.failed,
+                    "slo_violations": int(st["slo"]),
+                    "latency": _wf(st["lw"]),
+                    "cost": _wf(st["cw"]),
+                    "latency_p50": sketch.quantile(0.5),
+                    "latency_p95": sketch.quantile(0.95),
+                    "latency_p99": sketch.quantile(0.99),
+                    "sketch": sketch.state(),
+                    "sweeps": stats.sweeps,
+                    "epochs": stats.epochs,
+                    # the same dict as stats.host_s: the drain's own time
+                    # is added to it as this span closes
+                    "host_s": host_s,
+                }
+                stats.preempt_count = np.zeros(0, dtype=np.int64)
+                stats.outcome = []
+                return summary, stats
+
+            roc = np.asarray(st["roc"])
+            rsc = np.asarray(st["rsc"])
+            rct = np.asarray(st["rct"])
+            ru = np.asarray(st["ru"])
+            stats.done_t = np.asarray(st["rdn"]).copy()
+            stats.admit_t = np.asarray(st["rad"]).copy()
+            stats.preempt_count = np.asarray(st["rpc"]).astype(np.int64)
+            stats.outcome = [_OUTCOMES[int(o)] for o in roc]
+            results = []
+            for i in range(B):
+                lat = float(stats.done_t[i] - stats.arrival_t[i])
+                slo = bool(np.isfinite(cap_req[i])) and lat > cap_req[i] + _SLO_TOL
+                mods = trie.path(int(ru[i]))
+                results.append(ExecutionResult(
+                    success=bool(rsc[i]),
+                    total_cost=float(rct[i]),
+                    total_lat=lat,
+                    models=mods,
+                    n_stages=len(mods),
+                    replan_overhead_s=0.0,
+                    slo_violated=slo,
+                    outcome=stats.outcome[i],
+                ))
+            return results, stats
 
 
 def _wf(wt) -> dict:
@@ -1864,7 +2024,8 @@ def _empty_summary(stats: EventStats) -> dict:
             "slo_violations": 0,
             "latency": z, "cost": z, "latency_p50": float("nan"),
             "latency_p95": float("nan"), "latency_p99": float("nan"),
-            "sketch": QuantileSketch.log_spaced().state()}
+            "sketch": QuantileSketch.log_spaced().state(),
+            "sweeps": 0, "epochs": 0, "host_s": stats.host_s}
 
 
 def _init_state(jnp, cfg: _EngineConfig, B: int, arrs_sorted: np.ndarray):
@@ -1879,6 +2040,7 @@ def _init_state(jnp, cfg: _EngineConfig, B: int, arrs_sorted: np.ndarray):
         "ns": jnp.asarray(0, i64),
         "wtd": jnp.asarray(False),
         "ev": jnp.asarray(0, i64), "rp": jnp.asarray(0, i64),
+        "swp": jnp.asarray(0, i64),
         "adm": jnp.asarray(0, i64), "rej": jnp.asarray(0, i64),
         "shd": jnp.asarray(0, i64), "dgc": jnp.asarray(0, i64),
         "pre": jnp.asarray(0, i64), "res": jnp.asarray(0, i64),
@@ -1961,11 +2123,21 @@ def merge_stream_summaries(a: dict, b: dict) -> dict:
     Sketch merging validates the bin edges bitwise and raises
     ``ValueError`` when the two summaries were accumulated over different
     binnings (or when only one side carries a sketch) — a silent merge of
-    incompatible histograms would corrupt every reported quantile."""
+    incompatible histograms would corrupt every reported quantile.
+
+    The tracing counters ``sweeps``, ``epochs`` and the per-phase
+    ``host_s`` seconds add too, where either side carries them."""
     out = dict(a)
     for key in ("n_requests", "events", "replans", "served", "succeeded",
                 "rejected", "shed", "failed", "slo_violations"):
         out[key] = a[key] + b[key]
+    for key in ("sweeps", "epochs"):
+        if key in a or key in b:
+            out[key] = a.get(key, 0) + b.get(key, 0)
+    if "host_s" in a or "host_s" in b:
+        ha, hb = a.get("host_s", {}), b.get("host_s", {})
+        out["host_s"] = {k: ha.get(k, 0.0) + hb.get(k, 0.0)
+                         for k in {**ha, **hb}}
     for key in ("latency", "cost"):
         wa = (a[key]["count"], a[key]["mean"], a[key]["var"] * a[key]["count"])
         wb = (b[key]["count"], b[key]["mean"], b[key]["var"] * b[key]["count"])

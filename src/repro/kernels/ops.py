@@ -31,6 +31,9 @@ from repro.kernels.xla_trie import fleet_plan_blocked
 # blocked path (and small shapes may not tile evenly)
 _NAIVE_ATTN_ELEMS = 512 * 512
 _NAIVE_SSD_LEN = 256
+# the replan sweep's named scope: one of the event engine's four
+# (`repro.core.events_compiled.SCOPES`)
+PLAN_SCOPE = "vinelm/plan"
 
 
 def _interpret() -> bool:
@@ -176,28 +179,33 @@ def trie_plan(terminal, depth, acc, cost, lat, subtree_size, path_models,
     from prefix ``u`` only when ``blocked_depth[v] <= depth[u]``.  ``None``
     (or all-zeros) means every engine is up — identical plans to the
     pre-fault contract.
+
+    Every variant runs under the named scope `PLAN_SCOPE`, so the sweep's
+    operations carry it in their HLO metadata wherever the planner is
+    traced (the compiled event engine's width-1 sweeps included).
     """
-    if blocked_depth is None:
-        blocked_depth = jnp.zeros_like(terminal)
-    if use_pallas:
-        variant = "pallas"
-    if variant == "pallas":
-        return _pallas_trie_plan(
+    with jax.named_scope(PLAN_SCOPE):
+        if blocked_depth is None:
+            blocked_depth = jnp.zeros_like(terminal)
+        if use_pallas:
+            variant = "pallas"
+        if variant == "pallas":
+            return _pallas_trie_plan(
+                terminal, depth, acc, cost, lat, subtree_size, path_models,
+                path_counts, engine_of_model, prefixes, elapsed_lat,
+                elapsed_cost, engine_delays, acc_floor, cost_cap, lat_cap,
+                kind=kind, blocked_depth=blocked_depth, interpret=_interpret())
+        if variant == "fused":
+            return fleet_plan_blocked(
+                terminal, depth, acc, cost, lat, subtree_size, path_models,
+                path_counts, engine_of_model, prefixes, elapsed_lat,
+                elapsed_cost, engine_delays, acc_floor, cost_cap, lat_cap,
+                kind=kind, blocked_depth=blocked_depth)
+        if variant != "dense":
+            raise ValueError(
+                f"unknown trie_plan variant {variant!r}: {TRIE_PLAN_VARIANTS}")
+        return ref.fleet_plan(
             terminal, depth, acc, cost, lat, subtree_size, path_models,
-            path_counts, engine_of_model, prefixes, elapsed_lat,
-            elapsed_cost, engine_delays, acc_floor, cost_cap, lat_cap,
-            kind=kind, blocked_depth=blocked_depth, interpret=_interpret())
-    if variant == "fused":
-        return fleet_plan_blocked(
-            terminal, depth, acc, cost, lat, subtree_size, path_models,
-            path_counts, engine_of_model, prefixes, elapsed_lat,
-            elapsed_cost, engine_delays, acc_floor, cost_cap, lat_cap,
-            kind=kind, blocked_depth=blocked_depth)
-    if variant != "dense":
-        raise ValueError(
-            f"unknown trie_plan variant {variant!r}: {TRIE_PLAN_VARIANTS}")
-    return ref.fleet_plan(
-        terminal, depth, acc, cost, lat, subtree_size, path_models,
-        engine_of_model, prefixes, elapsed_lat, elapsed_cost,
-        engine_delays, acc_floor, cost_cap, lat_cap, kind=kind,
-        blocked_depth=blocked_depth)
+            engine_of_model, prefixes, elapsed_lat, elapsed_cost,
+            engine_delays, acc_floor, cost_cap, lat_cap, kind=kind,
+            blocked_depth=blocked_depth)

@@ -103,7 +103,8 @@ class _Captured(Exception):
     pass
 
 
-def test_epoch_step_compiles_for_v5e(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def v5e_step(one_chip):
     """One epoch step of the compiled engine (float64 virtual clock) at
     nl2sql_2 size: the operands are captured from a CPU run's first step
     call, then the real step is compiled for the chip."""
@@ -124,13 +125,14 @@ def test_epoch_step_compiles_for_v5e(one_chip, monkeypatch):
         return step
 
     from repro.core.runtime import make_workload_executor
-    monkeypatch.setattr(events_compiled, "_build_step", capture)
-    with pytest.raises(_Captured):
-        run_events(trie, ann, obj, np.arange(64), make_workload_executor(wl),
-                   arrivals=poisson_arrivals(64, 4.0, seed=1), capacity=16,
-                   policy="dynamic_load_aware", admission="feasibility",
-                   compiled=True)
-    monkeypatch.undo()
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        monkeypatch.setattr(events_compiled, "_build_step", capture)
+        with pytest.raises(_Captured):
+            run_events(trie, ann, obj, np.arange(64),
+                       make_workload_executor(wl),
+                       arrivals=poisson_arrivals(64, 4.0, seed=1),
+                       capacity=16, policy="dynamic_load_aware",
+                       admission="feasibility", compiled=True)
 
     step = events_compiled._build_step(seen["cfg"])
     args = jax.tree.map(
@@ -138,5 +140,23 @@ def test_epoch_step_compiles_for_v5e(one_chip, monkeypatch):
         seen["shapes"], is_leaf=lambda x: isinstance(x, tuple)
         and len(x) == 2 and isinstance(x[0], tuple))
     with jax.enable_x64(True):
-        compiled = step.lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes > 0
+        return step.lower(*args).compile()
+
+
+def test_epoch_step_compiles_for_v5e(v5e_step):
+    assert v5e_step.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_epoch_step_for_v5e_keeps_its_named_scopes(v5e_step):
+    """The chip's compiler keeps the step's scopes in its metadata: every
+    loop and branch of the step is named by one of the four."""
+    smap = events_compiled.hlo_scope_map(v5e_step.as_text())
+    names = set(smap.values())
+    for scope in events_compiled.SCOPES:
+        assert any(scope + "/" in n for n in names), scope
+    loops = [line for line in v5e_step.as_text().splitlines()
+             if " while(" in line or " conditional(" in line]
+    assert loops
+    for line in loops:
+        name = line.split(" = ")[0].split()[-1].lstrip("%")
+        assert "vinelm/" in smap.get(name, ""), line[:120]
