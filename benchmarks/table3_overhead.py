@@ -7,9 +7,9 @@ scales to fleets:
 
 - ``dense``  — the pre-fusion masked-reduction program (one full min-pass
   per lexicographic key, (N, Dmax) delay intermediate materialized);
-- ``fused``  — the blocked XLA mirror of the Pallas kernel (running
-  lexicographic minima across node tiles, path-counts delay matmul,
-  first-step gather fused into the pass) — the default serving path;
+- ``fused``  — the XLA mirror of the Pallas kernel (lexicographic minima
+  in one tile over the whole trie, path-counts delay matmul, first-step
+  gather fused into the pass) — the default serving path;
 - ``pallas`` — the fused Pallas kernel itself (interpret mode on CPU;
   compiled on TPU the tile pass maps 1:1 onto VMEM-resident trie tiles).
 

@@ -21,8 +21,8 @@ over the structure-of-arrays trie:
 The replan itself dispatches through `repro.kernels.ops.trie_plan`
 (ops.py-style ``use_pallas``/variant switch):
 
-- "fused" (default) — the blocked XLA mirror (`kernels/xla_trie.py`):
-  per-request running lexicographic minima carried across node tiles,
+- "fused" (default) — the XLA mirror (`kernels/xla_trie.py`):
+  per-request lexicographic minima in one tile over the whole trie,
   cumulative engine delay as a path-counts matmul, first-step gather fused
   into the tournament — no (N, Dmax) intermediate, no full-array min-pass;
 - "pallas" — the fused Pallas kernel (`kernels/trie_plan.py`), the same

@@ -105,7 +105,7 @@ from repro.core.events import _DEFAULT_CAPACITY, EventStats, _explore_tables
 from repro.core.runtime import ExecutionResult, StageExecutor
 from repro.core.streaming import QuantileSketch, welford_merge
 from repro.core.trie import Trie, TrieAnnotations
-from repro.kernels.ops import PLAN_SCOPE
+from repro.kernels.ops import PLAN_SCOPE, trie_plan_tiles
 
 # outcome codes inside the traced state (host strings on the way out)
 _OC_SERVED, _OC_REJECTED, _OC_SHED, _OC_FAILED = 0, 1, 2, 3
@@ -1138,16 +1138,17 @@ def _build_step(cfg: _EngineConfig):
 
         # Plan ONLY the lanes that need dispatch, one width-1 kernel sweep
         # per lane: the planner's math is lane-independent (per-request
-        # running minima over node tiles, identical tiling at any batch
-        # width), so the single-lane call is bit-identical to that lane of
-        # a capacity-wide call — but a steady-state event has 1-2 needy
+        # lexicographic minima, the same node whatever the tiling), so the
+        # single-lane call is bit-identical to that lane of a
+        # capacity-wide call — but a steady-state event has 1-2 needy
         # lanes, so this trades C full-trie sweeps for n_needed and is
         # what makes the engine trie-size-robust (the batched form was
-        # ~C x slower per event on the 5461-node MathQA trie).  Downgraded
-        # lanes pick the min-cost scalar bundle per lane instead of a
-        # second capacity-wide sweep (the host uses a float64 search;
-        # divergence is possible at float32 resolution and documented in
-        # EVENT_ENGINE.md).
+        # ~C x slower per event on the 5461-node MathQA trie).  The fused
+        # sweep is one node tile over the whole trie, with no loop inside.
+        # Downgraded lanes pick the min-cost scalar bundle per lane
+        # instead of a second capacity-wide sweep (the host uses a float64
+        # search; divergence is possible at float32 resolution and
+        # documented in EVENT_ENGINE.md).
         def plan_lane(c):
             tgt, nxt, done = c
             i = jnp.argmax(mine & ~done)
@@ -1816,7 +1817,9 @@ def run_events_compiled(
         step = _build_step(cfg)
         span.set_metadata(nodes=int(trie.n_nodes),
                           dmax=int(td.path_models.shape[1]),
-                          models=M, engines=E)
+                          models=M, engines=E,
+                          plan_tiles=trie_plan_tiles(int(trie.n_nodes),
+                                                     cfg.variant))
     with _host_span(host_s, "tabulate", requests=B):
         tab_s, tab_c, tab_l, row = _tabulate_executor(
             executor, requests, probe, t_start, work_model=work_model,

@@ -22,6 +22,7 @@ from repro.kernels.decode_attention import decode_attention as _pallas_decode
 from repro.kernels.flash_attention import flash_attention as _pallas_flash
 from repro.kernels.rmsnorm import rms_norm as _pallas_rmsnorm
 from repro.kernels.ssd_scan import ssd_scan as _pallas_ssd
+from repro.kernels.trie_plan import DEFAULT_BLOCK_NODES
 from repro.kernels.trie_plan import trie_plan_pallas as _pallas_trie_plan
 from repro.kernels.xla_flash import decode_attention_xla, flash_attention_xla
 from repro.kernels.xla_ssd import ssd_scan_chunked
@@ -152,6 +153,17 @@ def rms_norm(x, scale, eps=1e-6, *, use_pallas=False):
 TRIE_PLAN_VARIANTS = ("dense", "fused", "pallas")
 
 
+def trie_plan_tiles(n_nodes: int, variant: str) -> int:
+    """Node tiles one `trie_plan` sweep of ``variant`` runs over an
+    ``n_nodes``-node trie: the Pallas kernel's grid rows (its
+    `DEFAULT_BLOCK_NODES`-node tiles, narrowed to the padded trie); the
+    fused and dense variants take the whole trie at once."""
+    if variant != "pallas":
+        return 1
+    block = min(DEFAULT_BLOCK_NODES, -(-n_nodes // 128) * 128)
+    return -(-n_nodes // block)
+
+
 def trie_plan(terminal, depth, acc, cost, lat, subtree_size, path_models,
               path_counts, engine_of_model, prefixes, elapsed_lat,
               elapsed_cost, engine_delays, acc_floor, cost_cap, lat_cap,
@@ -164,8 +176,8 @@ def trie_plan(terminal, depth, acc, cost, lat, subtree_size, path_models,
 
     - "pallas" (or ``use_pallas=True``) -> the tiled Pallas kernel
       (``interpret=True`` on CPU, compiled on TPU);
-    - "fused"  -> the blocked XLA mirror (same tile math, jnp fori-loop) —
-      the default serving path and the form CPU CI benchmarks;
+    - "fused"  -> the XLA mirror (same tile math, one tile over the whole
+      trie) — the default serving path and the form CPU CI benchmarks;
     - "dense"  -> the pure-jnp reference (`ref.fleet_plan`): one full
       min-pass per lexicographic key with the (N, Dmax) delay intermediate
       materialized — the oracle tests compare against and the pre-fusion

@@ -25,8 +25,8 @@ Tie-breaking is exact: every comparison is on identical float32 key values
 (no epsilon-weighted composite keys), so the kernel picks the *same* node
 as the dense oracle and the host ``select_path`` — the property the fleet
 equivalence suites pin.  `xla_trie.fleet_plan_blocked` runs the identical
-tile math (same `_tile_lexmin_update` helper) as a jnp fori-loop: the XLA
-mirror for CPU CI, bitwise-aligned with interpret-mode Pallas.
+tile math (same `_tile_lexmin_update` helper) as one tile over the whole
+trie: the XLA mirror for CPU CI, bitwise-aligned with interpret-mode Pallas.
 
 One caveat on the dense oracle: the counts matmul groups the delay sum by
 model (count x delta) where the oracle sums by path position, so the two
@@ -96,7 +96,7 @@ def _tile_lexmin_update(carry, idx0, term_t, depth_t, acc_t, cost_t, lat_t,
     key triple seen so far, its global node index, and the first-step
     model id gathered when that node became the incumbent.  Pure jnp —
     executed identically by the Pallas kernel body and the XLA mirror's
-    fori-loop, so the two paths cannot drift.
+    single tile, so the two paths cannot drift.
 
     ``bd_t`` is the availability mask as a node row (``blocked_depth``:
     1 + deepest dead-engine stage position on the node's root path, 0 when

@@ -1,21 +1,24 @@
 """XLA mirror of the fused trie-replan kernel (`trie_plan.py`).
 
-Same blocked algorithm — per-request running lexicographic minima carried
-across node tiles, cumulative engine delay as a path-counts matmul, the
-first-step gather fused into the tournament — expressed as a jnp fori-loop
-instead of a Pallas grid.  This is the path CPU CI benchmarks and the
+Same fused algorithm — per-request lexicographic minima, cumulative engine
+delay as a path-counts matmul, the first-step gather fused into the
+tournament — expressed as one node tile over the whole trie instead of a
+Pallas grid of node tiles.  This is the path CPU CI benchmarks and the
 default `use_pallas=False` dispatch run; it executes the *same*
 `_tile_lexmin_update` helper as the kernel body, so the two cannot drift.
+
+The sweep takes no node-tile loop at any width: on a TPU v5e over the
+5,461-node mathqa_4 trie one tile beat 512-node tiles from 1 lane (6.8x)
+to 2,048 lanes, wider than any sweep the controllers issue (the table is
+in PERF.md).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.trie_plan import (
     BIG,
     BIG_IDX,
-    DEFAULT_BLOCK_NODES,
     _tile_lexmin_update,
     finalize,
     lane_columns,
@@ -31,25 +34,21 @@ def fleet_plan_blocked(
     *,
     kind: str,
     blocked_depth=None,
-    block_nodes: int = DEFAULT_BLOCK_NODES,
 ):
     """Fused fleet replan: (targets, next_models), both (B,) int32.
 
     Same contract as `ref.fleet_plan` / `trie_plan.trie_plan_pallas`;
     ``blocked_depth`` (N,) is the engine-availability mask as a node
-    column (see `_tile_lexmin_update`), ``None`` = every engine up.
+    column (see `_tile_lexmin_update`), ``None`` = every engine up.  The
+    whole trie, padded to a multiple of 8 nodes, is one tile: its
+    lexicographic narrowing keeps the lowest index on exact key ties, the
+    node the Pallas grid's cross-tile merge picks.
     """
     del elapsed_cost
     if blocked_depth is None:
         blocked_depth = jnp.zeros_like(terminal)
-    n = terminal.shape[0]
     bsz = prefixes.shape[0]
-    # small tries fit one tile: skip the loop machinery entirely (the
-    # running-minima pass degenerates to a single tile update)
-    if n <= 4 * block_nodes:
-        block_nodes = max((n + 7) // 8 * 8, 8)
-    n_pad = -(-n // block_nodes) * block_nodes
-    n_tiles = n_pad // block_nodes
+    n_pad = max(-(-terminal.shape[0] // 8) * 8, 8)
 
     lo, hi, du, lat_u, cost_u, delay_u, thr, pmd, cap_eff, floor_eff = \
         request_stats(depth, cost, lat, subtree_size, path_counts,
@@ -68,17 +67,7 @@ def fleet_plan_blocked(
         jnp.full((bsz, 1), BIG_IDX, jnp.int32),
         jnp.full((bsz, 1), -1.0, f32),
     )
-
-    def body(i, carry):
-        s = i * block_nodes
-        tiles = [jax.lax.dynamic_slice_in_dim(a, s, block_nodes, axis=1)
-                 for a in rows]
-        return _tile_lexmin_update(carry, s, *tiles, *cols, pmd, cap_eff,
-                                   floor_eff, kind=kind)
-
-    if n_tiles == 1:
-        carry = body(0, carry0)
-    else:
-        carry = jax.lax.fori_loop(0, n_tiles, body, carry0)
+    carry = _tile_lexmin_update(carry0, 0, *rows, *cols, pmd, cap_eff,
+                                floor_eff, kind=kind)
     tgt, nxt = finalize(carry, cols[0])
     return tgt[:, 0], nxt[:, 0]

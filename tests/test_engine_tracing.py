@@ -1,7 +1,8 @@
 """The compiled engine's spans, scopes and counters.
 
 - six host spans ``vinelm.build`` ... ``vinelm.drain`` per call, in order
-  and inside the caller's span, carrying the call's shape and counters;
+  and inside the caller's span, carrying the call's shape, the planner's
+  node tiles and the counters;
 - the always-on counters: ``sweeps`` (width-1 planner sweeps) against
   the host loop's planned lanes, ``epochs``, and ``host_s`` per phase;
 - `merge_stream_summaries` adds them;
@@ -26,6 +27,7 @@ from repro.core.events_compiled import (
 )
 from repro.core.runtime import make_workload_executor
 from repro.core.workload import poisson_arrivals
+from repro.kernels.ops import trie_plan_tiles
 from repro.serving.loadsim import EngineLoadModel, FleetLoadModel
 
 PHASES = ("build", "tabulate", "upload", "enqueue", "wait", "drain")
@@ -126,6 +128,7 @@ def test_a_traced_call_writes_six_spans_in_order_inside_the_callers(
     build, drain = spans[0][3], spans[-1][3]
     assert build["nodes"] == trie.n_nodes
     assert build["models"] == trie.template.n_models
+    assert build["plan_tiles"] == trie_plan_tiles(trie.n_nodes, "fused") == 1
     assert (drain["events"], drain["sweeps"], drain["epochs"]) == (
         summary["events"], summary["sweeps"], summary["epochs"])
 

@@ -99,6 +99,21 @@ def test_fused_planner_compiles_for_v5e(one_chip, mathqa_td, lanes):
     assert compiled.memory_analysis() is not None
 
 
+# 4,096 lanes: wider than any sweep the controllers issue
+@pytest.mark.parametrize("lanes", LANES + (4096,))
+def test_fused_planner_tiles_follow_the_working_set_for_v5e(one_chip,
+                                                            mathqa_td,
+                                                            lanes):
+    """A fused sweep over the 5,461-node trie compiles as one node tile
+    at every width: no ``while`` in its optimized v5e HLO."""
+    args, bd = _planner_shapes(mathqa_td, lanes, one_chip)
+    plan = functools.partial(fleet_plan_blocked, kind="max_acc")
+    hlo = jax.jit(
+        lambda *a, bd: plan(*a, blocked_depth=bd)).lower(
+            *args, bd=bd).compile().as_text()
+    assert " while(" not in hlo
+
+
 class _Captured(Exception):
     pass
 

@@ -21,7 +21,7 @@ from repro.core.controller_jax import (
     next_model_for,
     trie_engines,
 )
-from repro.core.trie import Trie
+from repro.core.trie import Trie, TrieAnnotations
 from repro.core.workload import generate_workload
 
 _SIZES = {"nl2sql_8": 300, "nl2sql_2": 300, "mathqa_4": 120}
@@ -258,3 +258,150 @@ def test_pallas_mode_follows_backend(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="'gpu'"):
         ops._interpret()
+
+
+# ----------------------------------------------------------------------
+# node tiling: the fused sweep's one tile against the Pallas grid's tiles
+# ----------------------------------------------------------------------
+
+
+def _on_grid(ann):
+    """Annotations on a coarse dyadic grid, exact in float32 and float64,
+    so that many candidates tie on every key and the lowest index
+    decides."""
+    return TrieAnnotations(acc=np.round(ann.acc * 4) / 4,
+                           cost=np.round(ann.cost * 128) / 128,
+                           lat=np.round(ann.lat / 2) * 2)
+
+
+def _off_grid(obj):
+    """The objective's budgets moved half a grid step off `_on_grid`'s
+    values, so that no candidate sits on a feasibility boundary."""
+    def mid(x, step):
+        return None if x is None else (np.floor(x / step) + 0.5) * step
+    return Objective(obj.kind, acc_floor=mid(obj.acc_floor, 1 / 4),
+                     cost_cap=mid(obj.cost_cap, 1 / 128),
+                     lat_cap=mid(obj.lat_cap, 2))
+
+
+def _host_plan(trie, ann, obj, engines, roots, el, delays, bd):
+    """Host `select_path` and first step per lane; candidates ``v`` with
+    ``bd[v] > depth[root]`` (a new stage on a down engine) are masked by
+    an infinite latency."""
+    tgt, nxt = [], []
+    for i, root in enumerate(roots):
+        lat = np.where(bd > trie.depth[root], np.inf, ann.lat)
+        t = select_path(trie, TrieAnnotations(ann.acc, ann.cost, lat), obj,
+                        root=int(root), elapsed_lat=float(el[i]),
+                        engine_delays={e: float(delays[i, j])
+                                       for j, e in enumerate(engines)})
+        tgt.append(t)
+        nxt.append(next_model_for(trie, int(root), t))
+    return np.array(tgt), np.array(nxt)
+
+
+@pytest.mark.parametrize("kind", ["max_acc", "min_cost"])
+@pytest.mark.parametrize("name", ["mathqa_4", "nl2sql_8"])
+@pytest.mark.parametrize("lanes", [1, 2, 24, 256])
+def test_fused_tilings_match_dense_oracle_and_host(lanes, name, kind):
+    """The fused sweep, one node tile over the whole trie, and the Pallas
+    grid of 512-node tiles pick the dense oracle's and the host's node and
+    first step at every width: random prefixes, exact key ties, prefixes
+    with no feasible plan, and an engine down.  On mathqa_4 (5,461 nodes,
+    11 Pallas tiles) the ties cross tiles; nl2sql_8 (585 nodes) is small."""
+    import jax
+
+    from repro.core.controller_jax import _objective_scalars
+    from repro.core.faults import blocked_depth_table
+    from repro.kernels import ops, ref
+
+    tpl, trie, ann = _setup(name)
+    engines = trie_engines(tpl)
+    n, n_eng = trie.n_nodes, len(engines)
+    rng = np.random.default_rng(lanes)
+    roots = rng.integers(0, n, size=lanes).astype(np.int32)
+    zeros = np.zeros(lanes, np.float32)
+    delays = rng.uniform(0, 0.5, (lanes, n_eng)).astype(np.float32)
+    clean = np.zeros(n, np.float32)
+    obj = {o.kind: o for o in _objectives(trie, ann)}[kind]
+    tied_ann, tied_obj = _on_grid(ann), _off_grid(obj)
+    down = np.zeros(n_eng, bool)
+    down[0] = True
+    td0 = TrieDevice.build(trie, ann)
+    blocked = blocked_depth_table(np.asarray(td0.path_models),
+                                  np.asarray(td0.engine_of_model), down)
+    assert blocked.max() > 0
+    el = rng.uniform(0, 3, lanes).astype(np.float32)
+    over = np.where(np.arange(lanes) % 2 == 0,
+                    np.float32(obj.lat_cap + 100.0), el)
+    tied_roots = roots.copy()
+    tied_roots[0] = 0
+    # dyadic delays keep every key exact; the root lane's are zero
+    tied_delays = rng.integers(0, 4, (lanes, n_eng)).astype(np.float32) / 8
+    tied_delays[0] = 0.0
+    cases = {
+        "random": (ann, obj, roots, el, delays, clean),
+        "ties": (tied_ann, tied_obj, tied_roots, zeros, tied_delays, clean),
+        "infeasible": (ann, obj, roots, over, delays, clean),
+        "blocked": (ann, obj, roots, el, delays, blocked),
+    }
+    for case, (a, o, r, e, d, bd) in cases.items():
+        td = td0 if a is ann else TrieDevice.build(trie, a)
+        lane_ops = (r, e, zeros, d, *_objective_scalars(o))
+        cols = (td.terminal, td.depth, td.acc, td.cost, td.lat,
+                td.subtree_size, td.path_models, td.path_counts,
+                td.engine_of_model, *lane_ops)
+        want = _host_plan(trie, a, o, engines, r, e, d, bd)
+        dense = ref.fleet_plan(td.terminal, td.depth, td.acc, td.cost,
+                               td.lat, td.subtree_size, td.path_models,
+                               td.engine_of_model, *lane_ops, kind=kind,
+                               blocked_depth=bd)
+        for i, got in enumerate(dense):
+            np.testing.assert_array_equal(got, want[i],
+                                          err_msg=f"{case}/dense")
+        for variant in ("fused", "pallas"):
+            tiles = ops.trie_plan_tiles(n, variant)
+
+            def plan(*c):
+                return ops.trie_plan(*c, kind=kind, variant=variant,
+                                     blocked_depth=bd)
+
+            for i, got in enumerate(jax.jit(plan)(*cols)):
+                np.testing.assert_array_equal(
+                    got, want[i], err_msg=f"{case}/{variant} ({tiles} tiles)")
+        if case == "infeasible":
+            assert want[0][0] == -1
+        if case == "ties":
+            # the root lane's winner shares its whole key with another
+            # feasible candidate, so the index tie-break decides it
+            d_lat, d_cost = a.lat - a.lat[0], a.cost - a.cost[0]
+            feas = trie.terminal & (d_lat <= o.lat_cap)
+            if o.cost_cap is not None:
+                feas &= a.cost <= o.cost_cap
+            if kind == "min_cost":
+                feas &= a.acc >= o.acc_floor
+                key = np.stack([d_cost, d_lat, trie.depth])
+            else:
+                key = np.stack([-a.acc, d_cost, d_lat])
+            w = want[0][0]
+            assert w >= 0
+            tied = np.nonzero(feas & np.all(key == key[:, [w]], axis=0))[0]
+            assert len(tied) > 1
+            if ops.trie_plan_tiles(n, "pallas") > 1:
+                # ... and one of them lies in another tile of the Pallas
+                # grid, so the cross-tile merge decides it there
+                assert np.any(tied // 512 != w // 512)
+
+
+@pytest.mark.parametrize("n_nodes,variant,want", [
+    # the event engine's sweep over the 5,461-node trie
+    (5461, "fused", 1),
+    (5461, "dense", 1),
+    # the Pallas grid: 512-node tiles, narrowed to a small trie
+    (5461, "pallas", 11),
+    (585, "pallas", 2),
+    (31, "pallas", 1),
+])
+def test_plan_tiles_shapes(n_nodes, variant, want):
+    from repro.kernels.ops import trie_plan_tiles
+    assert trie_plan_tiles(n_nodes, variant) == want
