@@ -6,7 +6,9 @@ change to the program can move the yardstick:
 - `question_tables` is the synthetic ground-truth generator of the
   workflow's questions (per-question, per-position, per-model success,
   dollar cost and latency), the arithmetic of the program's
-  ``generate_workload``;
+  ``generate_workload``; for a configuration with a ``fleet`` section it
+  also gives each stage's decode tokens, and prices and times them by
+  the engines' curves (`fleet.py`);
 - `poisson_arrivals` and `trace_arrivals` are the program's arrival
   generators, draw for draw: a mix of kind ``trace`` extends a short
   Poisson stub by bootstrap;
@@ -24,6 +26,8 @@ from __future__ import annotations
 
 import numpy as np
 
+import fleet as fleet_count
+
 ARRIVAL_KINDS = ("trace", "gamma")
 
 
@@ -39,8 +43,8 @@ def stream_seed(seed: int, *words: int) -> int:
 # question tables
 # ----------------------------------------------------------------------
 def question_tables(models: list[dict], depth: int, n_questions: int,
-                    seed: int, *, interaction: float = 0.06,
-                    depth_decay: float = 0.92):
+                    seed: int, *, fleet: dict | None = None,
+                    interaction: float = 0.06, depth_decay: float = 0.92):
     """(S, cost, lat): (n_questions, depth, M) success (uint8), dollar
     cost and seconds of each model at each invocation position.
 
@@ -49,20 +53,28 @@ def question_tables(models: list[dict], depth: int, n_questions: int,
     request-model interaction; log-normal output tokens drive the cost
     (price per 1k tokens) and the latency (base + per token + gamma
     noise).  Draw order and arithmetic are those of the program's
-    generator, so one seed gives the same tables in both."""
+    generator, so one seed gives the same tables in both.
+
+    With a ``fleet`` section, (S, cost, lat, decode): the same z, eps and
+    S; whole decode tokens log-normal around the fleet's median, longer
+    for harder questions and weaker models; and ``lat`` the stage's
+    batch-1 seconds on its model's engine, ``prompt_tokens[d] x
+    prefill_tok_s + decode x step(1)``, with no noise."""
     rng = np.random.default_rng(seed)
     D, M = depth, len(models)
     z = rng.beta(1.8, 2.6, size=n_questions)
     power = np.array([m["power"] for m in models])
     price = np.array([m["price"] for m in models])
-    base_lat = np.array([m["base_latency"] for m in models])
-    tok_lat = np.array([m["per_token_latency"] for m in models])
     eps = interaction * rng.standard_normal((n_questions, M))
     decay = depth_decay ** np.arange(D)
     pi = (power[None, None, :] * decay[None, :, None]
           * (1.0 - z[:, None, None]) + eps[:, None, :])
     pi = np.clip(pi, 0.005, 0.97)
     S = (rng.random((n_questions, D, M)) < pi).astype(np.uint8)
+    if fleet is not None:
+        return (S, *_fleet_tables(models, D, fleet, z, power, price, rng))
+    base_lat = np.array([m["base_latency"] for m in models])
+    tok_lat = np.array([m["per_token_latency"] for m in models])
     mu_tok = (np.log(260.0) + 0.35 * z[:, None, None]
               + 0.1 * (1 - power)[None, None, :])
     tokens = rng.lognormal(mean=mu_tok, sigma=0.45, size=(n_questions, D, M))
@@ -70,6 +82,36 @@ def question_tables(models: list[dict], depth: int, n_questions: int,
     lat = (base_lat[None, None, :] + tok_lat[None, None, :] * tokens
            + rng.gamma(2.0, 0.05, size=(n_questions, D, M)))
     return S, cost, lat.astype(np.float64)
+
+
+def config_tables(config: dict):
+    """The question tables of a configuration, drawn from its own
+    ``questions_seed``."""
+    wf = config["workflow"]
+    return question_tables(wf["models"], len(wf["stages"]),
+                           int(config["questions"]),
+                           int(config["questions_seed"]),
+                           fleet=config.get("fleet"))
+
+
+def _fleet_tables(models, D, fleet, z, power, price, rng):
+    """(cost, lat, decode) of a fleet configuration's questions."""
+    prompt = np.asarray(fleet["prompt_tokens"], dtype=np.float64)
+    if prompt.shape != (D,):
+        raise ValueError(f"prompt_tokens: one per stage ({D}), got "
+                         f"{prompt.shape}")
+    curves = fleet_count.engines(fleet)
+    pf = np.array([curves[m["engine"]].prefill_tok_s for m in models])
+    step1 = np.array([curves[m["engine"]].step(1.0) for m in models])
+    dec = fleet["decode_tokens"]
+    mu = (np.log(float(dec["median"])) + 0.35 * z[:, None, None]
+          + 0.1 * (1 - power)[None, None, :])
+    decode = np.maximum(1.0, np.rint(rng.lognormal(
+        mean=mu, sigma=float(dec["sigma"]), size=(z.size, D, len(models)))))
+    cost = price[None, None, :] * decode / 1000.0
+    lat = (prompt[None, :, None] * pf[None, None, :]
+           + decode * step1[None, None, :])
+    return cost, lat, decode
 
 
 # ----------------------------------------------------------------------

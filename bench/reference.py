@@ -19,6 +19,16 @@ program's code:
 - the engine calendar: processor sharing at ``max(1, occupancy /
   concurrency)`` slowdown, drained between events on a clock of the
   configuration's ``precision.clock`` (float64);
+- for a configuration with a ``fleet`` section, the token calendar
+  instead: each engine's decode step over a batch of ``b`` sequences
+  takes ``step(b) = max(t_w + t_kv * b, t_f * b)`` (`fleet.py`'s own
+  count of the published architecture), at most ``kv_cap`` sequences
+  share a step, so each of ``occ`` sequences on the engine drains its
+  batch-1 seconds of work at ``(b / occ) * (step(1) / step(b))`` with
+  ``b = min(max(occ, 1), kv_cap)``; the planner's delay for an engine
+  with ``n`` sequences is ``(slowdown(n) - 1) x mean service``, where
+  ``slowdown(n) = ((n + 1) / b) * (step(b) / step(1))`` and ``b = min(n
+  + 1, kv_cap)``;
 - feasibility admission: a FIFO queue over a fixed number of slots,
   rejection of queued requests whose burned budget rules out the fastest
   unloaded plan, rejection or shed when the planner finds no feasible
@@ -37,6 +47,8 @@ import collections
 import math
 
 import numpy as np
+
+import fleet as fleet_count
 
 SERVED, REJECTED, SHED = 0, 1, 2
 
@@ -70,7 +82,7 @@ class Reference:
         self.stages = wf["stages"]
         self.D = len(self.stages)
         self.M = len(self.models)
-        S, cost, lat = tables
+        S, cost, lat = tables[:3]
         self.nq = S.shape[0]
         self.capacity = int(config["capacity"])
         if config["policy"] not in ("dynamic", "dynamic_load_aware"):
@@ -79,7 +91,12 @@ class Reference:
         if config["admission"] not in ("always", "feasibility"):
             raise ValueError(f"admission {config['admission']!r}")
         self.gates = config["admission"] == "feasibility"
-        self.conc = float(config["engine_concurrency"])
+        fleet = config.get("fleet")
+        if fleet is None:
+            self.conc = float(config["engine_concurrency"])
+        elif any(float(s["tool_latency"]) for s in wf["stages"]):
+            raise ValueError("a fleet's token calendar times a stage by its "
+                             "tokens alone: tool latency is not served")
         obj = config["objective"]
         if obj["kind"] != "max_acc":
             raise ValueError(f"objective {obj['kind']!r}")
@@ -111,6 +128,12 @@ class Reference:
             float(np.mean(lat[:, :, [j for j, m in enumerate(self.models)
                                      if m["engine"] == e]]))
             for e in self.engines]
+        # per engine (t_w, t_kv, t_f, kv_cap, step(1)) of the token calendar
+        self.tok = None
+        if fleet is not None:
+            curves = fleet_count.engines(fleet)
+            self.tok = [(k.t_w, k.t_kv, k.t_f, k.kv_cap, k.step(1.0))
+                        for k in (curves[e] for e in self.engines)]
 
         f32 = np.float32
         self.acc32 = self.acc.astype(f32)
@@ -223,7 +246,10 @@ class Reference:
     # ------------------------------------------------------------------
     def simulate(self, reqs, arrivals, *, planner: str | None = None,
                  clock: str | None = None):
-        """Serve one call's requests; returns the summary dict.
+        """Serve one call's requests; returns the summary dict (for a fleet
+        configuration with the engine-seconds of virtual time that the
+        token calendar spent batching, ``batched_s``, and above a KV cap,
+        ``over_cap_s``).
 
         ``planner`` and ``clock`` default to the configuration's stated
         precisions.  ``planner="bfloat16"`` plans, and ``clock="float32"``
@@ -269,26 +295,54 @@ class Reference:
         success = [False] * B
         total_cost = [0.0] * B
         n_stages = [0] * B
-        n = {"events": 0, "replans": 0, "rejected": 0, "shed": 0}
+        # engine-seconds with 1 < occupancy <= KV cap, and above the cap
+        n = {"events": 0, "replans": 0, "rejected": 0, "shed": 0,
+             "batched_s": 0.0, "over_cap_s": 0.0}
         pending: collections.deque = collections.deque()
         ap = 0
+
+        def rate(o, e):
+            # the drain rate of each of o > 0 sequences on engine e
+            if self.tok is None:
+                return c(1.0 / max(1.0, (float(o - 1) + 1.0) / self.conc))
+            tw, tkv, tf, cap_e, s1 = self.tok[e]
+            b = min(float(o), cap_e)
+            sb = c(max(c(tw + c(tkv * b)), c(tf * b)))
+            return c(c(b / o) * c(s1 / sb))
 
         def rates():
             occ = [0] * E
             for e in je:
                 if e >= 0:
                     occ[e] += 1
-            return occ, [c(1.0 / max(1.0, (float(o - 1) + 1.0) / self.conc))
-                         if o > 0 else 1.0 for o in occ]
+            return occ, [rate(o, e) if o > 0 else 1.0
+                         for e, o in enumerate(occ)]
+
+        def delay(o, e):
+            # the planner's extra seconds on engine e with o sequences
+            if self.tok is None:
+                sd = max(1.0, (float(o) + 1.0) / self.conc)
+            else:
+                tw, tkv, tf, cap_e, s1 = self.tok[e]
+                k = float(o) + 1.0
+                b = min(k, cap_e)
+                sd = (k / b) * (max(tw + tkv * b, tf * b) / s1)
+            return (sd - 1.0) * self.mean_service[e]
 
         def advance(t):
             nonlocal t_last
             dt = c(t - t_last)
             if dt > 0.0:
-                _, r = rates()
+                occ, r = rates()
                 for s in range(C):
                     if je[s] >= 0:
                         rem[s] = c(rem[s] - c(dt * r[je[s]]))
+                if self.tok is not None:
+                    for e, o in enumerate(occ):
+                        if o > self.tok[e][3]:
+                            n["over_cap_s"] += dt
+                        elif o > 1:
+                            n["batched_s"] += dt
             t_last = max(t_last, t)
 
         def next_completion():
@@ -384,10 +438,8 @@ class Reference:
                 pmd = np.zeros(self.M, dtype=np.float32)
                 if self.load_aware:
                     occ, _ = rates()
-                    row = np.array(
-                        [(max(1.0, (float(occ[e]) + 1.0) / self.conc) - 1.0)
-                         * self.mean_service[e] for e in range(E)],
-                        dtype=np.float64).astype(np.float32)
+                    row = np.array([delay(occ[e], e) for e in range(E)],
+                                   dtype=np.float64).astype(np.float32)
                     pmd = row[self.eom]
                 plans = [self.plan(pre[s], np.float32(t - arr_l[owner[s]]),
                                    pmd, bf16) for s in lanes]
@@ -416,6 +468,8 @@ class Reference:
 
         served = [i for i in range(B) if outcome[i] == SERVED]
         lat = [c(done_t[i] - arr_l[i]) for i in range(B)]
+        fleet = {} if self.tok is None else {
+            "batched_s": n["batched_s"], "over_cap_s": n["over_cap_s"]}
         return {
             "n_requests": B,
             "events": n["events"],
@@ -427,4 +481,5 @@ class Reference:
             "slo_violations": sum(1 for x in lat if x > cap + SLO_TOL),
             "latency_sum": math.fsum(lat[i] for i in served),
             "cost_sum": math.fsum(total_cost[i] for i in served),
+            **fleet,
         }

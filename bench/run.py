@@ -177,8 +177,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     # the deployment's question table is part of its configuration: the
     # program bakes numbers derived from it into its compiled step, so a
     # table drawn per seed would compile a program in every run's set-up
-    tables = gen.question_tables(wf["models"], len(wf["stages"]), nq,
-                                 int(config["questions_seed"]))
+    tables = gen.config_tables(config)
     phases.append(("tables", time.perf_counter()))
     dep = deploy.build(config, tables)
     phases.append(("deployment", time.perf_counter()))

@@ -24,6 +24,7 @@ import deploy
 import gen
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DATA = os.path.join(BENCH, "tests", "data")
 # (configuration, traffic mix): the cells, and the pairs whose cells wait
 # for a measurement on the chip (PERF.md, Open questions)
 with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
@@ -31,10 +32,15 @@ with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
 PAIRS += [p for p in (("nl2sql_2.c64", "burst6"),
                       ("mathqa_4.c32", "steady8.live"),
                       ("mathqa_4.c32.x4", "steady8")) if p not in PAIRS]
+# and the test fleet configuration: the token calendar's step
+PAIRS += [(os.path.join(DATA, "nemo_reflect.c16.json"),
+           os.path.join(DATA, "poisson01.json"))]
 
 
 def _load(kind, name):
-    with open(os.path.join(BENCH, kind, name + ".json")) as f:
+    path = name if os.path.isabs(name) else os.path.join(BENCH, kind,
+                                                        name + ".json")
+    with open(path) as f:
         return json.load(f)
 
 
@@ -60,18 +66,16 @@ class _Captured(Exception):
     pass
 
 
-@pytest.mark.parametrize("config,traffic", PAIRS)
+@pytest.mark.parametrize("config,traffic", PAIRS, ids=lambda p: (
+    os.path.basename(p)[:-len(".json")] if os.path.isabs(p) else p))
 def test_cell_step_compiles_for_v5e(topo, config, traffic, monkeypatch):
     from repro.core import events_compiled
     from repro.dist import sharding
 
     config, mix = _load("configs", config), _load("traffic", traffic)
     chips = int(config["devices"])
-    wf = config["workflow"]
     nq = int(config["questions"])
-    tables = gen.question_tables(wf["models"], len(wf["stages"]), nq,
-                                 int(config["questions_seed"]))
-    dep = deploy.build(config, tables)
+    dep = deploy.build(config, gen.config_tables(config))
     mesh = Mesh(np.array(topo.devices[:chips]), (sharding.LANE_AXIS,))
     seen = {}
 
