@@ -147,6 +147,10 @@ class EventStats:
     sweeps: int = 0
     epochs: int = 0
     host_s: dict = dataclasses.field(default_factory=dict)
+    # compiled token calendar only: engine-seconds of virtual time with
+    # 1 < occupancy <= KV cap (batching), and above the cap (queueing)
+    batched_s: float = 0.0
+    over_cap_s: float = 0.0
     replan_s: list = dataclasses.field(default_factory=list)
     planned_per_replan: list = dataclasses.field(default_factory=list)
     peak_occupancy: dict = dataclasses.field(default_factory=dict)

@@ -64,13 +64,17 @@ availability mask).
 
 Tracing (docs/EVENT_ENGINE.md, "Spans, scopes and counters"): every
 event phase of the step runs under one of four named scopes (`SCOPES`),
-which only annotate the compiled program's HLO metadata, and every call
+which only annotate the compiled program's HLO metadata; under the token
+calendar its rates, advance and delay row run under a fifth,
+`CALENDAR_SCOPE`, nested in the clock's and the dispatch's.  Every call
 opens six host spans ``vinelm.build`` ... ``vinelm.drain``, recorded
 while a `jax.profiler` session is open.  The host phases' wall times, the
 count of width-1 planner sweeps and of step dispatches are counted in
 every call (`EventStats.host_s`/``sweeps``/``epochs`` and the streamed
-summary).  `engine_scope_maps` joins a device trace's operation names to
-the scopes.
+summary), and under the token calendar the engine-seconds spent batching
+under a KV cap and above one (``batched_s``, ``over_cap_s``).
+`engine_scope_maps` joins a device trace's operation names to the
+scopes.
 """
 from __future__ import annotations
 
@@ -123,6 +127,9 @@ DEFAULT_EPOCH = 1024  # arrivals per jitted step (throughput knob, not math)
 # sweeps themselves (`repro.kernels.ops.trie_plan`)
 SCOPES = ("vinelm/clock", "vinelm/admit", "vinelm/dispatch", PLAN_SCOPE)
 _CLOCK, _ADMIT, _DISPATCH, _ = SCOPES
+# the token calendar's rates, advance and planner delay row (token mode
+# only), nested in the clock's or the dispatch's scope where each runs
+CALENDAR_SCOPE = "vinelm/calendar"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,8 +395,9 @@ def _build_step(cfg: _EngineConfig):
             jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)].add(
             jnp.where(act, 1.0, 0.0))[:E]
         if cfg.tokens:
-            rates = traced_token_rates(occ, cn["tkw"], cn["tkv"],
-                                       cn["tkf"], cn["tkc"], cn["tk1"])
+            with jax.named_scope(CALENDAR_SCOPE):
+                rates = traced_token_rates(occ, cn["tkw"], cn["tkv"],
+                                           cn["tkf"], cn["tkc"], cn["tk1"])
         else:
             rates = traced_engine_rates(occ, cn["conc"])
         return traced_job_rates(st["je"], st["jw"], act, rates, st["wtd"])
@@ -405,6 +413,22 @@ def _build_step(cfg: _EngineConfig):
         q = jnp.where(act, jnp.maximum(st["jrm"], 0.0)
                       / jnp.where(act, jr, 1.0), jnp.inf)
         return jnp.where(act.any(), st["tl"] + jnp.min(q), jnp.inf)
+
+    def calendar_seconds(st, cn, t, act):
+        """Engine-seconds of the advance to ``t`` spent batching (1 <
+        occupancy <= KV cap) and above a KV cap, added engine by engine
+        to the ``bts``/``ocs`` counters."""
+        dt = t - st["tl"]
+        occ = jnp.zeros(E + 1, dt.dtype).at[
+            jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)].add(
+            jnp.where(act, 1.0, 0.0))[:E]
+        over = (dt > 0.0) & (occ > cn["tkc"])
+        batched = (dt > 0.0) & (occ > 1.0) & ~over
+        bts, ocs = st["bts"], st["ocs"]
+        for e in range(E):
+            bts = bts + jnp.where(batched[e], dt, 0.0)
+            ocs = ocs + jnp.where(over[e], dt, 0.0)
+        return {**st, "bts": bts, "ocs": ocs}
 
     def peak_update(st, cn):
         act = st["je"] >= 0
@@ -532,7 +556,7 @@ def _build_step(cfg: _EngineConfig):
                 st["shd"] = st["shd"] + jnp.sum(jnp.where(doomed, 1, 0))
             st["rpp"] = scat_set(st["rpp"], req, False, doomed)
             keep = activep & ~doomed
-            tgt = jnp.where(keep, jnp.cumsum(keep) - 1, P)
+            tgt = jnp.where(keep, jnp.cumsum(keep, dtype=i32) - 1, P)
             new_row = jnp.full((P,), -1, i32).at[tgt].set(row, mode="drop")
             st["pb"] = st["pb"].at[k].set(new_row)
             st["pn"] = st["pn"].at[k].set(
@@ -1052,24 +1076,25 @@ def _build_step(cfg: _EngineConfig):
             act = st["je"] >= 0
             park = jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)
             if cfg.tokens:
-                # TokenWorkModel.delays over the live sequence COUNT (the
-                # KV/batch physics depends on how many sequences share the
-                # decode step, never on priority weights); slowdown mirror
-                # of EngineTokenModel.slowdown with the same barriers as
-                # traced_token_rates so host == compiled bitwise
-                occw = jnp.zeros(E + 1, st["sec"].dtype).at[park].add(
-                    jnp.where(act, 1.0, 0.0))[:E]
-                n = occw + 1.0
-                b = jnp.minimum(n, cn["tkc"])
-                prod = lax.optimization_barrier(cn["tkv"] * b)
-                sb = jnp.maximum(cn["tkw"] + prod, cn["tkf"] * b)
-                q1 = lax.optimization_barrier(n / b)
-                q2 = lax.optimization_barrier(sb / cn["tk1"])
-                sd = lax.optimization_barrier(q1 * q2)
-                dr64 = (sd - 1.0) * cn["ms"]
-                # the host casts the dict values into a float32 row first
-                delay_row = jnp.where(cn["hasm"], dr64,
-                                      0.0).astype(jnp.float32)
+                with jax.named_scope(CALENDAR_SCOPE):
+                    # TokenWorkModel.delays over the live sequence COUNT (the
+                    # KV/batch physics depends on how many sequences share the
+                    # decode step, never on priority weights); slowdown mirror
+                    # of EngineTokenModel.slowdown with the same barriers as
+                    # traced_token_rates so host == compiled bitwise
+                    occw = jnp.zeros(E + 1, st["sec"].dtype).at[park].add(
+                        jnp.where(act, 1.0, 0.0))[:E]
+                    n = occw + 1.0
+                    b = jnp.minimum(n, cn["tkc"])
+                    prod = lax.optimization_barrier(cn["tkv"] * b)
+                    sb = jnp.maximum(cn["tkw"] + prod, cn["tkf"] * b)
+                    q1 = lax.optimization_barrier(n / b)
+                    q2 = lax.optimization_barrier(sb / cn["tk1"])
+                    sd = lax.optimization_barrier(q1 * q2)
+                    dr64 = (sd - 1.0) * cn["ms"]
+                    # the host casts the dict values into a float32 row first
+                    delay_row = jnp.where(cn["hasm"], dr64,
+                                          0.0).astype(jnp.float32)
             elif cfg.ps:
                 # FleetLoadModel.delays over the live (weighted) occupancy
                 occw = jnp.zeros(E + 1, st["sec"].dtype).at[park].add(
@@ -1268,8 +1293,10 @@ def _build_step(cfg: _EngineConfig):
         st["sec"] = jnp.where(start_m, st["sec"] + c, st["sec"])
         st["sm"] = jnp.where(start_m, nxt, st["sm"])
         st["sok"] = jnp.where(start_m, sres, st["sok"])
-        # calendar starts, seq assigned in ascending slot order
-        rank = jnp.cumsum(jnp.where(start_m, 1, 0)) - 1
+        # calendar starts, seq assigned in ascending slot order; the scan
+        # runs in int32: the TPU emulates a 64-bit one in scoped VMEM,
+        # which a few hundred slots overflow
+        rank = jnp.cumsum(jnp.where(start_m, 1, 0), dtype=i32) - 1
         st["jsq"] = jnp.where(start_m, st["ns"] + rank, st["jsq"])
         st["ns"] = st["ns"] + jnp.sum(jnp.where(start_m, 1, 0))
         st["je"] = jnp.where(start_m, cn["eom"][nxtc], st["je"])
@@ -1359,13 +1386,20 @@ def _build_step(cfg: _EngineConfig):
         # dispatch phases open their own
         t = st["tn"]
         st = {**st, "ev": st["ev"] + 1, "snd": jnp.zeros(C, bool)}
-        if cfg.ps:
+        if cfg.tokens:
             act = st["je"] >= 0
-            tok = (cn["tkw"], cn["tkv"], cn["tkf"], cn["tkc"],
-                   cn["tk1"]) if cfg.tokens else None
+            with jax.named_scope(CALENDAR_SCOPE):
+                st = calendar_seconds(st, cn, t, act)
+                jrm, tl = traced_advance(
+                    st["jrm"], st["tl"], t, st["je"], st["jw"], act,
+                    cn["conc"], st["wtd"],
+                    tok=(cn["tkw"], cn["tkv"], cn["tkf"], cn["tkc"],
+                         cn["tk1"]))
+            st = {**st, "jrm": jrm, "tl": tl}
+        elif cfg.ps:
+            act = st["je"] >= 0
             jrm, tl = traced_advance(st["jrm"], st["tl"], t, st["je"],
-                                     st["jw"], act, cn["conc"], st["wtd"],
-                                     tok=tok)
+                                     st["jw"], act, cn["conc"], st["wtd"])
             st = {**st, "jrm": jrm, "tl": tl}
         st = phase_completions(st, cn, t)
         if cfg.fault_outages:
@@ -1954,8 +1988,14 @@ def run_events_compiled(
             stats.peak_occupancy = {
                 e: int(v) for e, v in zip(engines, np.asarray(st["po"]))}
             sketch.merge_counts(np.asarray(st["hist"]), edges=sketch.edges)
+            calendar = {}
+            if tokens:
+                stats.batched_s = float(st["bts"])
+                stats.over_cap_s = float(st["ocs"])
+                calendar = {"batched_s": stats.batched_s,
+                            "over_cap_s": stats.over_cap_s}
             span.set_metadata(events=stats.events, sweeps=stats.sweeps,
-                              epochs=stats.epochs)
+                              epochs=stats.epochs, **calendar)
             if stream:
                 # constant-memory path: per-request columns stay on device and
                 # are never materialized as host-side python lists; the summary
@@ -1979,6 +2019,7 @@ def run_events_compiled(
                     "sketch": sketch.state(),
                     "sweeps": stats.sweeps,
                     "epochs": stats.epochs,
+                    **calendar,
                     # the same dict as stats.host_s: the drain's own time
                     # is added to it as this span closes
                     "host_s": host_s,
@@ -2114,6 +2155,10 @@ def _init_state(jnp, cfg: _EngineConfig, B: int, arrs_sorted: np.ndarray):
         })
     if cfg.pol.wants_forecast:
         st["dead"] = jnp.zeros(B, bool)
+    if cfg.tokens:
+        # engine-seconds batching under a KV cap, and above one
+        st["bts"] = jnp.asarray(0.0, f64)
+        st["ocs"] = jnp.asarray(0.0, f64)
     return st
 
 
@@ -2128,13 +2173,14 @@ def merge_stream_summaries(a: dict, b: dict) -> dict:
     binnings (or when only one side carries a sketch) — a silent merge of
     incompatible histograms would corrupt every reported quantile.
 
-    The tracing counters ``sweeps``, ``epochs`` and the per-phase
-    ``host_s`` seconds add too, where either side carries them."""
+    The tracing counters ``sweeps``, ``epochs``, the token calendar's
+    ``batched_s`` and ``over_cap_s`` and the per-phase ``host_s`` seconds
+    add too, where either side carries them."""
     out = dict(a)
     for key in ("n_requests", "events", "replans", "served", "succeeded",
                 "rejected", "shed", "failed", "slo_violations"):
         out[key] = a[key] + b[key]
-    for key in ("sweeps", "epochs"):
+    for key in ("sweeps", "epochs", "batched_s", "over_cap_s"):
         if key in a or key in b:
             out[key] = a.get(key, 0) + b.get(key, 0)
     if "host_s" in a or "host_s" in b:

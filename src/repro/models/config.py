@@ -11,9 +11,12 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2 style; minicpm3)."""
+    """Multi-head Latent Attention (DeepSeek-V2 style; minicpm3).
 
-    q_lora_rank: int = 768
+    ``q_lora_rank`` 0 or None projects the query directly from the
+    hidden state (DeepSeek-V2-Lite), with no query latent or its norm."""
+
+    q_lora_rank: int | None = 768
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
@@ -28,6 +31,9 @@ class MoEConfig:
     expert_group: int = 512      # tokens per dispatch group (memory knob)
     dense_residual: bool = False  # arctic: dense MLP in parallel with MoE
     dense_d_ff: int = 0           # width of the dense residual MLP
+    n_shared: int = 0             # shared experts, every token takes them
+    d_expert: int = 0             # one expert's width; 0 means d_ff
+    first_k_dense: int = 0        # leading layers with a dense d_ff MLP
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,9 +118,10 @@ class ArchConfig:
         return True  # all assigned archs are (or contain) decoders
 
     def param_count(self) -> int:
-        """Analytic parameter count (embeddings + blocks), for roofline."""
+        """Analytic parameter count (embeddings, blocks, final norm), for
+        roofline."""
         d, f, V, L = self.d_model, self.d_ff, self.vocab, self.n_layers
-        emb = V * d * (1 if self.tie_embeddings else 2)
+        emb = V * d * (1 if self.tie_embeddings else 2) + d
         per_layer = 0
         if self.family in ("ssm", "hybrid"):
             s = self.ssm
@@ -141,25 +148,31 @@ class ArchConfig:
             return emb + ssm_total + attn_total
         if self.attn_kind == "mla":
             m = self.mla
+            H = self.n_heads
             qk = m.qk_nope_head_dim + m.qk_rope_head_dim
-            attn = (
-                d * m.q_lora_rank + m.q_lora_rank * self.n_heads * qk
-                + d * (m.kv_lora_rank + m.qk_rope_head_dim)
-                + m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
-                + self.n_heads * m.v_head_dim * d
+            if m.q_lora_rank:
+                # query latent, its RMSNorm, and the up-projection
+                attn = d * m.q_lora_rank + m.q_lora_rank + m.q_lora_rank * H * qk
+            else:
+                attn = d * H * qk
+            attn += (
+                d * (m.kv_lora_rank + m.qk_rope_head_dim) + m.kv_lora_rank
+                + m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+                + H * m.v_head_dim * d
             )
         else:
             hd = self.head_dim
             attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        dense_mlp = 3 * d * f
+        mlp_total = L * dense_mlp
         if self.moe is not None:
-            mlp = self.moe.n_experts * 3 * d * f
-            if self.moe.dense_residual:
-                mlp += 3 * d * self.moe.dense_d_ff
-            mlp += d * self.moe.n_experts  # router
-        else:
-            mlp = 3 * d * f
-        per_layer = attn + mlp + 2 * d
-        total = emb + L * per_layer
+            e = self.moe
+            mlp = (e.n_experts + e.n_shared) * 3 * d * self._expert_width
+            if e.dense_residual:
+                mlp += 3 * d * e.dense_d_ff
+            mlp += d * e.n_experts  # router
+            mlp_total = e.first_k_dense * dense_mlp + (L - e.first_k_dense) * mlp
+        total = emb + L * (attn + 2 * d) + mlp_total
         if self.encdec is not None:
             total += self.encdec.n_encoder_layers * (attn + 3 * d * f + 2 * d)
             total += L * attn  # decoder cross-attention blocks
@@ -167,13 +180,27 @@ class ArchConfig:
             total += self.vlm.patch_dim * d  # frontend projection
         return int(total)
 
+    @property
+    def _expert_width(self) -> int:
+        return self.moe.d_expert or self.d_ff
+
     def active_param_count(self) -> int:
-        """Active parameters per token (MoE: top-k experts only)."""
+        """Active parameters per token (MoE: the top-k routed experts of
+        each expert layer, not all of them)."""
         if self.moe is None:
             return self.param_count()
-        d, f, L = self.d_model, self.d_ff, self.n_layers
-        inactive = L * (self.moe.n_experts - self.moe.top_k) * 3 * d * f
+        e = self.moe
+        inactive = ((self.n_layers - e.first_k_dense) * (e.n_experts - e.top_k)
+                    * 3 * self.d_model * self._expert_width)
         return int(self.param_count() - inactive)
+
+    def kv_bytes_per_token(self, bytes_per_param: float) -> float:
+        """KV-cache bytes a token over all layers: keys and values of every
+        KV head (GQA/MHA), or the latent and the shared rope key (MLA)."""
+        if self.attn_kind == "mla":
+            m = self.mla
+            return self.n_layers * (m.kv_lora_rank + m.qk_rope_head_dim) * bytes_per_param
+        return 2 * self.n_layers * self.n_kv_heads * self.head_dim * bytes_per_param
 
 
 @dataclasses.dataclass(frozen=True)

@@ -16,6 +16,7 @@ controller's delta_e(t) terms (§4.3).
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import numpy as np
 
@@ -131,6 +132,77 @@ class LoadTrace:
 PEAK_FLOPS = 197e12   # bf16 FLOP/s per chip
 HBM_BW = 819e9        # bytes/s per chip
 
+# keys of a published config.json that `published_arch` counts; multi-token
+# prediction modules are read and not served
+PUBLISHED_COUNTED = frozenset({
+    "hidden_size", "num_hidden_layers", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "intermediate_size", "vocab_size",
+    "tie_word_embeddings",
+    "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim",
+    "n_routed_experts", "n_shared_experts", "num_experts_per_tok",
+    "moe_intermediate_size", "first_k_dense_replace", "moe_layer_freq",
+    "num_nextn_predict_layers",
+})
+# keys it models at these values only
+PUBLISHED_ASSUMED = {"attention_bias": False, "hidden_act": "silu",
+                     "sliding_window": None}
+# keys that change no parameter count and no KV cache
+PUBLISHED_NO_SHAPE = frozenset({
+    "rms_norm_eps", "rope_theta", "rope_scaling", "max_position_embeddings",
+    "model_type", "n_group", "topk_group", "topk_method", "scoring_func",
+    "routed_scaling_factor", "norm_topk_prob", "seq_aux", "ep_size",
+})
+MLA_KEYS = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+            "v_head_dim")
+MOE_KEYS = ("n_routed_experts", "num_experts_per_tok",
+            "moe_intermediate_size")
+
+
+def published_arch(name: str, published: dict):
+    """The `ArchConfig` of a published ``config.json`` (``published``, its
+    keys verbatim): dense GQA/MHA, or latent attention with or without a
+    query latent; routed and shared experts after leading dense layers.
+    Multi-token prediction modules are not served and not counted.
+
+    Raises `NotImplementedError` naming every key it does not model;
+    keys that change no count (rope scaling, routing groups, ...) pass."""
+    from repro.models.config import ArchConfig, MLAConfig, MoEConfig
+
+    p = published
+    unknown = sorted(set(p) - PUBLISHED_COUNTED - PUBLISHED_NO_SHAPE
+                     - set(PUBLISHED_ASSUMED))
+    unknown += sorted(f"{k}={p[k]!r}" for k, v in PUBLISHED_ASSUMED.items()
+                      if k in p and p[k] != v)
+    if p.get("moe_layer_freq", 1) != 1:
+        unknown.append(f"moe_layer_freq={p['moe_layer_freq']!r}")
+    if unknown:
+        raise NotImplementedError(
+            f"{name}: the program does not model {', '.join(unknown)}")
+    H = int(p["num_attention_heads"])
+    mla = moe = None
+    if any(k in p for k in MLA_KEYS):
+        mla = MLAConfig(q_lora_rank=int(p.get("q_lora_rank") or 0),
+                        kv_lora_rank=int(p["kv_lora_rank"]),
+                        qk_nope_head_dim=int(p["qk_nope_head_dim"]),
+                        qk_rope_head_dim=int(p["qk_rope_head_dim"]),
+                        v_head_dim=int(p["v_head_dim"]))
+    if any(k in p for k in MOE_KEYS):
+        moe = MoEConfig(n_experts=int(p["n_routed_experts"]),
+                        top_k=int(p["num_experts_per_tok"]),
+                        n_shared=int(p.get("n_shared_experts", 0)),
+                        d_expert=int(p["moe_intermediate_size"]),
+                        first_k_dense=int(p.get("first_k_dense_replace", 0)))
+    return ArchConfig(
+        name=name, family="dense" if moe is None else "moe",
+        n_layers=int(p["num_hidden_layers"]),
+        d_model=int(p["hidden_size"]), n_heads=H,
+        n_kv_heads=int(p.get("num_key_value_heads", H)),
+        head_dim=int(p["head_dim"]) if p.get("head_dim") else None,
+        d_ff=int(p["intermediate_size"]), vocab=int(p["vocab_size"]),
+        tie_embeddings=bool(p.get("tie_word_embeddings", False)),
+        attn_kind="gqa" if mla is None else "mla", mla=mla, moe=moe)
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineTokenModel:
@@ -186,19 +258,38 @@ class EngineTokenModel:
         """Derive the decode-step curve from an `ArchConfig` and the
         chip roofline (same constants as `benchmarks/roofline.py`):
         weight stream = active params x bytes / HBM bandwidth, KV stream
-        = 2 x layers x kv_heads x head_dim x bytes per token x context
-        length, compute = 2 x active params FLOPs per token, and the KV
-        cap = how many ``context_len`` sequences fit the KV budget."""
+        = the architecture's KV bytes a token (`kv_bytes_per_token`) x
+        context length, compute = 2 x active params FLOPs per token, and
+        the KV cap = how many ``context_len`` sequences fit the KV budget
+        (at least one)."""
         p = float(arch.active_param_count())
-        kv_per_tok = max(2.0 * arch.n_layers * arch.n_kv_heads
-                         * arch.head_dim * bytes_per_param, 1.0)
-        cap = float(int(kv_budget_bytes // (kv_per_tok * context_len)))
+        kv_seq = max(float(arch.kv_bytes_per_token(bytes_per_param)),
+                     1.0) * context_len
+        t_f = 2.0 * p / peak_flops
         return cls(name,
                    t_weights_s=p * bytes_per_param / hbm_bw,
-                   t_kv_s=kv_per_tok * context_len / hbm_bw,
-                   t_flop_s=2.0 * p / peak_flops,
-                   kv_capacity=max(cap, 1.0),
-                   prefill_tok_s=2.0 * p / peak_flops)
+                   t_kv_s=kv_seq / hbm_bw,
+                   t_flop_s=t_f,
+                   kv_capacity=float(max(1, math.floor(
+                       kv_budget_bytes / kv_seq))),
+                   prefill_tok_s=t_f)
+
+    @classmethod
+    def from_published(cls, name: str, published: dict, *, chip: dict,
+                       replica_chips: int, kv_budget_bytes: float,
+                       context_len: int,
+                       bytes_per_param: float) -> "EngineTokenModel":
+        """The curve of an engine serving the architecture of a published
+        ``config.json`` (``published``, its keys verbatim) on a replica of
+        ``replica_chips`` chips: `published_arch` counts it (and raises on
+        a key it does not model), and `from_roofline` times it at the
+        replica's peaks, ``chip``'s ``peak_flops`` and ``hbm_bw`` summed
+        over its chips."""
+        return cls.from_roofline(
+            name, published_arch(name, published), context_len=context_len,
+            kv_budget_bytes=kv_budget_bytes, bytes_per_param=bytes_per_param,
+            peak_flops=replica_chips * float(chip["peak_flops"]),
+            hbm_bw=replica_chips * float(chip["hbm_bw"]))
 
     def decode_step_s(self, batch: float) -> float:
         """Seconds per decode step over a batch of ``batch`` sequences."""
