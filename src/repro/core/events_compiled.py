@@ -24,8 +24,11 @@ Architecture (see docs/EVENT_ENGINE.md for the full design):
   array in one state pytree — slot columns, the `FleetEngineSim` calendar
   columns (drained via `repro.serving.loadsim.traced_advance`), per-class
   FIFO rings over a precomputed arrival-order table, a fixed-capacity
-  paused buffer for preempted work, per-request outputs, and the
-  streaming accumulators.  The admission queue is not a heap: within a
+  paused buffer for preempted work, per-request outputs (not in stream
+  mode, whose drain reads only counters), and the streaming
+  accumulators.  Sums over the slots are one-hot reductions, never
+  slot-wide scatters (docs/EVENT_ENGINE.md, "Streaming mode and
+  sharding").  The admission queue is not a heap: within a
   class, priority order IS arrival order, so a (head, tail) ring per
   class plus an unrolled K-way merge by (class weight, arrival seq)
   reproduces the host heap's pop order exactly.
@@ -170,6 +173,9 @@ class _EngineConfig:
     max_retries: int = 0   # retry budget (exhaustion compare is traced-free)
     paused_cap: int = 0    # paused-buffer rows per class (C normally; B
     #                        under outages, whose victims can stack past C)
+    # stream mode carries no per-request result columns (the constant-
+    # memory drain reads only counters, moments and the histogram)
+    stream: bool = False
 
 
 _ENGINE_CACHE: dict[_EngineConfig, Callable] = {}
@@ -293,8 +299,9 @@ def _build_step(cfg: _EngineConfig):
     from jax import lax
 
     from repro.dist.sharding import LANE_AXIS
-    from repro.serving.loadsim import traced_advance, traced_engine_rates, \
-        traced_job_rates, traced_token_rates
+    from repro.serving.loadsim import traced_advance, traced_engine_count, \
+        traced_engine_rates, traced_engine_sum, traced_job_rates, \
+        traced_token_rates
 
     C, K, E, M = cfg.capacity, cfg.n_classes, cfg.n_engines, cfg.n_models
     P = cfg.paused_cap
@@ -310,10 +317,6 @@ def _build_step(cfg: _EngineConfig):
         """Masked scatter into a (B,)-indexed array (drop when ~mask)."""
         B = dst.shape[0]
         return dst.at[jnp.where(mask, idx, B)].set(val, mode="drop")
-
-    def scat_add(dst, idx, val, mask):
-        B = dst.shape[0]
-        return dst.at[jnp.where(mask, idx, B)].add(val, mode="drop")
 
     def wmerge(wt, cnt, mean, m2):
         """Fold a batch (count, mean, M2) into running Welford state —
@@ -335,24 +338,29 @@ def _build_step(cfg: _EngineConfig):
         return cnt, mean, m2
 
     def record_terminal(st, cn, req, valid, t, outcome, cost):
-        """Every terminal disposition funnels through here: outputs,
-        done-counter, and the streaming accumulators (latency/cost moments
-        and the quantile histogram over SERVED requests, SLO-violation
-        count over all terminal requests)."""
-        B = st["roc"].shape[0]
+        """Every terminal disposition funnels through here: outputs (not
+        in stream mode), done-counter, and the streaming accumulators
+        (latency/cost moments and the quantile histogram over SERVED
+        requests, SLO-violation count over all terminal requests)."""
+        B = cn["arr"].shape[0]
         reqc = jnp.clip(req, 0, B - 1)
         st = dict(st)
-        st["roc"] = scat_set(st["roc"], req, outcome, valid)
-        st["rdn"] = scat_set(st["rdn"], req, t, valid)
-        st["rct"] = scat_set(st["rct"], req, cost, valid)
+        if not cfg.stream:
+            st["roc"] = scat_set(st["roc"], req, outcome, valid)
+            st["rdn"] = scat_set(st["rdn"], req, t, valid)
+            st["rct"] = scat_set(st["rct"], req, cost, valid)
         st["don"] = st["don"] + jnp.sum(jnp.where(valid, 1, 0))
         lat = t - cn["arr"][reqc]
         served = valid & (outcome == _OC_SERVED)
         st["lw"] = wmerge(st["lw"], *batch_stats(lat, served))
         st["cw"] = wmerge(st["cw"], *batch_stats(cost, served))
-        bins = jnp.searchsorted(cn["edges"], lat, side="right")
-        st["hist"] = st["hist"].at[jnp.where(
-            served, bins, cfg.n_bins)].add(1, mode="drop")
+        # the bin by one compare over the edges (the index side="right"
+        # gives, with no binary-search loop), added as a one-hot count
+        bins = jnp.searchsorted(cn["edges"], lat, side="right",
+                                method="compare_all")
+        hit = served[:, None] & (bins[:, None] == jnp.arange(cfg.n_bins))
+        st["hist"] = st["hist"] + jnp.sum(hit, axis=0, dtype=i32).astype(
+            st["hist"].dtype)
         cap = cn["cap"][reqc]
         st["slo"] = st["slo"] + jnp.sum(jnp.where(
             valid & jnp.isfinite(cap) & (lat > cap + _SLO_TOL), 1, 0))
@@ -389,11 +397,13 @@ def _build_step(cfg: _EngineConfig):
             else jnp.maximum(st["jtc"] - t, 0.0)
         return jnp.where(act, rem, jnp.inf)
 
+    def occupancy(st, act, dtype):
+        """(E,) active jobs per engine, a one-hot count."""
+        return traced_engine_count(st["je"], act, E).astype(dtype)
+
     def job_rates(st, cn):
         act = st["je"] >= 0
-        occ = jnp.zeros(E + 1, st["jrm"].dtype).at[
-            jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)].add(
-            jnp.where(act, 1.0, 0.0))[:E]
+        occ = occupancy(st, act, st["jrm"].dtype)
         if cfg.tokens:
             with jax.named_scope(CALENDAR_SCOPE):
                 rates = traced_token_rates(occ, cn["tkw"], cn["tkv"],
@@ -419,9 +429,7 @@ def _build_step(cfg: _EngineConfig):
         occupancy <= KV cap) and above a KV cap, added engine by engine
         to the ``bts``/``ocs`` counters."""
         dt = t - st["tl"]
-        occ = jnp.zeros(E + 1, dt.dtype).at[
-            jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)].add(
-            jnp.where(act, 1.0, 0.0))[:E]
+        occ = occupancy(st, act, dt.dtype)
         over = (dt > 0.0) & (occ > cn["tkc"])
         batched = (dt > 0.0) & (occ > 1.0) & ~over
         bts, ocs = st["bts"], st["ocs"]
@@ -431,10 +439,7 @@ def _build_step(cfg: _EngineConfig):
         return {**st, "bts": bts, "ocs": ocs}
 
     def peak_update(st, cn):
-        act = st["je"] >= 0
-        occ = jnp.zeros(E + 1, jnp.int64).at[
-            jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)].add(
-            jnp.where(act, 1, 0))[:E]
+        occ = occupancy(st, st["je"] >= 0, st["po"].dtype)
         return {**st, "po": jnp.maximum(st["po"], occ)}
 
     # ------------------------------------------------------------------
@@ -600,12 +605,14 @@ def _build_step(cfg: _EngineConfig):
         newu = cn["child"][st["su"], jnp.clip(st["sm"], 0, M - 1)]
         st = dict(st)
         st["su"] = jnp.where(done, newu, st["su"])
-        st["ru"] = scat_set(st["ru"], own, newu, done)
         st["sm"] = jnp.where(done, -1, st["sm"])
         fin = done & st["sok"]
         deep = done & ~st["sok"] & (cn["depth"][newu] >= cfg.max_depth)
         term = fin | deep
-        st["rsc"] = scat_set(st["rsc"], own, True, fin)
+        st["nsc"] = st["nsc"] + jnp.sum(jnp.where(fin, 1, 0))
+        if not cfg.stream:
+            st["ru"] = scat_set(st["ru"], own, newu, done)
+            st["rsc"] = scat_set(st["rsc"], own, True, fin)
         st = record_terminal(st, cn, own, term, t,
                              jnp.full(C, _OC_SERVED, i32), st["sec"])
         st["snd"] = st["snd"] | (done & ~term)
@@ -790,7 +797,8 @@ def _build_step(cfg: _EngineConfig):
                     s = record_terminal(s, cn, one, tt, t,
                                         jnp.full(1, _OC_REJECTED, i32),
                                         jnp.zeros(1, s["sec"].dtype))
-                    s["rad"] = s["rad"].at[i].set(t)
+                    if not cfg.stream:
+                        s["rad"] = s["rad"].at[i].set(t)
                     s["rej"] = s["rej"] + 1
                     return {**s, "qh": s["qh"].at[k].add(1)}
 
@@ -890,7 +898,8 @@ def _build_step(cfg: _EngineConfig):
                 jnp.full(1, ec_term))
             s["shd"] = s["shd"] + jnp.where(doom_p, 1, 0)
             s["rej"] = s["rej"] + jnp.where(rej, 1, 0)
-            s["rad"] = scat_set(s["rad"], one, t, jnp.full(1, rej))
+            if not cfg.stream:
+                s["rad"] = scat_set(s["rad"], one, t, jnp.full(1, rej))
             if cfg.priorities:
                 s["rpp"] = scat_set(s["rpp"], one, False,
                                     jnp.full(1, doom_p))
@@ -975,7 +984,8 @@ def _build_step(cfg: _EngineConfig):
             s["rpec"] = s["rpec"].at[i].set(s["sec"][victim])
             s["rpdg"] = s["rpdg"].at[i].set(s["sdg"][victim])
             s["pre"] = s["pre"] + 1
-            s["rpc"] = s["rpc"].at[i].add(1)
+            if not cfg.stream:
+                s["rpc"] = s["rpc"].at[i].add(1)
             s = sim_clear(s, onehot_c)
             s = release(s, onehot_c)
             return paused_insert(s, cn, i, cn["cls"][i])
@@ -1051,8 +1061,9 @@ def _build_step(cfg: _EngineConfig):
                 s["res"] = s["res"] + jnp.where(isrs, 1, 0)
                 s = lax.cond(isrs, lambda ss: peak_update(ss, cn),
                              lambda ss: ss, s)
-            s["rad"] = jnp.where(isp, s["rad"],
-                                 s["rad"].at[i].set(t))
+            if not cfg.stream:
+                s["rad"] = jnp.where(isp, s["rad"],
+                                     s["rad"].at[i].set(t))
             s["adm"] = s["adm"] + jnp.where(isp, 0, 1)
             s["snd"] = s["snd"] | (onehot_c & (~isp | isrp))
             return s
@@ -1074,7 +1085,6 @@ def _build_step(cfg: _EngineConfig):
         delay_row = jnp.zeros(E, jnp.float32)
         if cfg.load_aware:
             act = st["je"] >= 0
-            park = jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)
             if cfg.tokens:
                 with jax.named_scope(CALENDAR_SCOPE):
                     # TokenWorkModel.delays over the live sequence COUNT (the
@@ -1082,9 +1092,7 @@ def _build_step(cfg: _EngineConfig):
                     # decode step, never on priority weights); slowdown mirror
                     # of EngineTokenModel.slowdown with the same barriers as
                     # traced_token_rates so host == compiled bitwise
-                    occw = jnp.zeros(E + 1, st["sec"].dtype).at[park].add(
-                        jnp.where(act, 1.0, 0.0))[:E]
-                    n = occw + 1.0
+                    n = occupancy(st, act, st["sec"].dtype) + 1.0
                     b = jnp.minimum(n, cn["tkc"])
                     prod = lax.optimization_barrier(cn["tkv"] * b)
                     sb = jnp.maximum(cn["tkw"] + prod, cn["tkf"] * b)
@@ -1097,9 +1105,8 @@ def _build_step(cfg: _EngineConfig):
                                           0.0).astype(jnp.float32)
             elif cfg.ps:
                 # FleetLoadModel.delays over the live (weighted) occupancy
-                occw = jnp.zeros(E + 1, st["sec"].dtype).at[park].add(
-                    jnp.where(act,
-                              st["jw"] if cfg.priorities else 1.0, 0.0))[:E]
+                occw = traced_engine_sum(st["je"], act, st["jw"], E) \
+                    if cfg.priorities else occupancy(st, act, st["sec"].dtype)
                 dr64 = (jnp.maximum(1.0, (occw + 1.0) / cn["conc"]) - 1.0) \
                     * cn["ms"]
                 # the host casts the dict values into a float32 row first
@@ -1115,6 +1122,10 @@ def _build_step(cfg: _EngineConfig):
                     rem = jnp.where(act,
                                     jnp.maximum(st["jtc"] - t, 0.0), 0.0)
                     jr = jnp.where(act, 1.0, 0.0)
+                # scatter-adds: the host sums these arbitrary floats in
+                # slot order (`np.bincount`), which a one-hot reduction's
+                # order would not keep bit for bit
+                park = jnp.where(act, jnp.clip(st["je"], 0, E - 1), E)
                 backlog = jnp.zeros(E + 1, rem.dtype).at[park].add(rem)[:E]
                 rate = jnp.zeros(E + 1, rem.dtype).at[park].add(jr)[:E]
                 drain = jnp.where(rate > 0, backlog / rate, 0.0)
@@ -1847,7 +1858,8 @@ def run_events_compiled(
             # outage victims can stack past C across repeated outages, so the
             # paused buffer is sized B under fault injection (shapes already
             # carry B-sized columns — no retrace cost)
-            paused_cap=B if fault_outages else (C if priorities else 0))
+            paused_cap=B if fault_outages else (C if priorities else 0),
+            stream=bool(stream))
         step = _build_step(cfg)
         span.set_metadata(nodes=int(trie.n_nodes),
                           dmax=int(td.path_models.shape[1]),
@@ -2006,7 +2018,7 @@ def run_events_compiled(
                     "events": stats.events,
                     "replans": stats.replans,
                     "served": B - stats.rejected - stats.shed - stats.failed,
-                    "succeeded": int(jnp.sum(st["rsc"])),
+                    "succeeded": int(st["nsc"]),
                     "rejected": stats.rejected,
                     "shed": stats.shed,
                     "failed": stats.failed,
@@ -2089,6 +2101,7 @@ def _init_state(jnp, cfg: _EngineConfig, B: int, arrs_sorted: np.ndarray):
         "shd": jnp.asarray(0, i64), "dgc": jnp.asarray(0, i64),
         "pre": jnp.asarray(0, i64), "res": jnp.asarray(0, i64),
         "don": jnp.asarray(0, i64), "slo": jnp.asarray(0, i64),
+        "nsc": jnp.asarray(0, i64),
         "po": jnp.zeros(E, i64),
         "so": jnp.full(C, -1, i32),
         "su": jnp.zeros(C, i32),
@@ -2107,13 +2120,6 @@ def _init_state(jnp, cfg: _EngineConfig, B: int, arrs_sorted: np.ndarray):
         "jw": jnp.ones(C, f64),
         "qh": jnp.zeros(K, i32),
         "qt": jnp.zeros(K, i32),
-        "roc": jnp.full(B, _OC_SERVED, i32),
-        "rsc": jnp.zeros(B, bool),
-        "rct": jnp.zeros(B, f64),
-        "rdn": jnp.zeros(B, f64),
-        "rad": jnp.zeros(B, f64),
-        "ru": jnp.zeros(B, i32),
-        "rpc": jnp.zeros(B, i32),
         "lw": (jnp.asarray(0.0, f64), jnp.asarray(0.0, f64),
                jnp.asarray(0.0, f64)),
         "cw": (jnp.asarray(0.0, f64), jnp.asarray(0.0, f64),
@@ -2121,6 +2127,17 @@ def _init_state(jnp, cfg: _EngineConfig, B: int, arrs_sorted: np.ndarray):
         "hist": jnp.zeros(cfg.n_bins, i64),
         "xpc": jnp.asarray(0, i64),
     }
+    if not cfg.stream:
+        # per-request results, materialized by the drain (written only)
+        st.update({
+            "roc": jnp.full(B, _OC_SERVED, i32),
+            "rsc": jnp.zeros(B, bool),
+            "rct": jnp.zeros(B, f64),
+            "rdn": jnp.zeros(B, f64),
+            "rad": jnp.zeros(B, f64),
+            "ru": jnp.zeros(B, i32),
+            "rpc": jnp.zeros(B, i32),
+        })
     if cfg.priorities or cfg.fault_outages:
         st.update({
             "pb": jnp.full((K, P), -1, i32),

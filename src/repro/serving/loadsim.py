@@ -842,6 +842,31 @@ class FleetEngineSim:
 # (numpy-only) for hosts that never touch the compiled path.
 
 
+def traced_engine_sum(job_engine, active, vals, n_engines):
+    """(E,) per-engine sums of the (C,) slot column ``vals`` over the
+    ``active`` lanes (``job_engine`` names each lane's engine).
+
+    A one-hot masked reduction, not a scatter-add: XLA:TPU runs a
+    scatter one update at a time, masked-out lanes included, so a
+    slot-wide scatter costs in proportion to the slots on every event.
+    Sums of integer counts are exact in any order; sums of weights are
+    exact when the weights are exactly summable (see
+    `traced_job_rates`)."""
+    import jax.numpy as jnp
+
+    hit = active[:, None] & (job_engine[:, None] == jnp.arange(n_engines))
+    return jnp.sum(jnp.where(hit, vals[:, None], 0.0), axis=0)
+
+
+def traced_engine_count(job_engine, active, n_engines):
+    """(E,) int32 count of the ``active`` lanes per engine (exact), the
+    one-hot reduction of `traced_engine_sum`."""
+    import jax.numpy as jnp
+
+    hit = active[:, None] & (job_engine[:, None] == jnp.arange(n_engines))
+    return jnp.sum(hit, axis=0, dtype=jnp.int32)
+
+
 def traced_engine_rates(occ, conc):
     """(E,) shared processor-sharing rate per engine — the traced image of
     `FleetEngineSim._rates` under the standard `EngineLoadModel` slowdown
@@ -906,17 +931,16 @@ def traced_job_rates(job_engine, weight, active, rates, weighted):
     (unspecified) order vs numpy's sequential `bincount`; the result is
     bit-identical whenever the weights are exactly summable (integers /
     small powers of two — the priority-class convention), which is what
-    the differential oracle pins."""
+    the differential oracle pins.  The per-engine sums and counts are
+    one-hot reductions (`traced_engine_sum`), so no iteration scatters."""
     import jax.numpy as jnp
     from jax import lax
 
     E = rates.shape[0]
     je_safe = jnp.clip(job_engine, 0, E - 1)
-    je_park = jnp.where(active, je_safe, E)  # park idle lanes off-engine
     base = jnp.where(active, rates[je_safe], 0.0)
 
-    occ = jnp.zeros(E + 1, base.dtype).at[je_park].add(
-        jnp.where(active, 1.0, 0.0))[:E]
+    occ = traced_engine_count(job_engine, active, E).astype(base.dtype)
     remaining0 = occ * rates
 
     def cond(c):
@@ -925,9 +949,7 @@ def traced_job_rates(job_engine, weight, active, rates, weighted):
     def body(c):
         _, r, fixed, remaining = c
         free = active & ~fixed
-        freef = jnp.where(free, 1.0, 0.0)
-        sumw = jnp.zeros(E + 1, base.dtype).at[je_park].add(
-            weight * freef)[:E]
+        sumw = traced_engine_sum(job_engine, free, weight, E)
         sumw_safe = jnp.where(sumw > 0.0, sumw, 1.0)
         share = jnp.where(free,
                           remaining[je_safe] * weight / sumw_safe[je_safe],
@@ -940,8 +962,8 @@ def traced_job_rates(job_engine, weight, active, rates, weighted):
         r = jnp.where(newly, 1.0, r)
         r = jnp.where(any_free & ~any_new & free, share, r)
         fixed = fixed | newly
-        remaining = remaining - jnp.zeros(E + 1, base.dtype).at[
-            je_park].add(jnp.where(newly, 1.0, 0.0))[:E]
+        remaining = remaining - traced_engine_count(
+            job_engine, newly, E).astype(base.dtype)
         done = ~any_free | (any_free & ~any_new)
         return done, r, fixed, remaining
 
@@ -967,10 +989,8 @@ def traced_advance(remaining, t_last, t, job_engine, weight, active,
     import jax.numpy as jnp
 
     dt = t - t_last
-    occ = jnp.zeros(conc.shape[0] + 1, remaining.dtype).at[
-        jnp.where(active, jnp.clip(job_engine, 0, conc.shape[0] - 1),
-                  conc.shape[0])].add(
-        jnp.where(active, 1.0, 0.0))[:conc.shape[0]]
+    occ = traced_engine_count(job_engine, active,
+                              conc.shape[0]).astype(remaining.dtype)
     rates = (traced_token_rates(occ, *tok) if tok is not None
              else traced_engine_rates(occ, conc))
     jr = traced_job_rates(job_engine, weight, active, rates, weighted)

@@ -6,9 +6,11 @@ covers what the oracle cannot:
 
 - host-vs-compiled identity on *branching* tries with the full workload
   generator (realistic annotations, load coupling, admission gates);
-- the ``stream=True`` constant-memory path: summary consistency against
-  the materialized per-request results, Welford moments, quantile-sketch
-  resolution, and the no-O(n)-host-lists guarantee;
+- the ``stream=True`` constant-memory path, on each calendar and with
+  priority classes and faults: summary equal bit for bit to what the
+  materialized run's state holds, and consistent with its per-request
+  results (Welford moments, quantile-sketch resolution); the stream
+  state without per-request columns, and no O(n) host lists;
 - `merge_stream_summaries` exactness for sharded replays;
 - dispatch plumbing: the ``compiled=`` switch in `run_events`, kwarg
   validation, and the NotImplementedError fence around host-only
@@ -19,6 +21,7 @@ import pytest
 from fleetlib import assert_results_identical, random_setup
 
 from repro.core.admission import AdmissionPolicy
+from repro.core.faults import FaultSchedule
 from repro.core.controller import Objective
 from repro.core.events import run_events
 from repro.core.events_compiled import (
@@ -26,8 +29,14 @@ from repro.core.events_compiled import (
     run_events_compiled,
 )
 from repro.core.runtime import make_workload_executor
+from repro.core.streaming import QuantileSketch, welford_finalize
 from repro.core.workload import SLOClass, poisson_arrivals, sample_classes
-from repro.serving.loadsim import EngineLoadModel, FleetLoadModel
+from repro.serving.loadsim import (
+    EngineLoadModel,
+    EngineTokenModel,
+    FleetLoadModel,
+    TokenWorkModel,
+)
 
 
 def _serving_setup(seed, n=24, rate=3.0):
@@ -110,21 +119,96 @@ def test_compiled_matches_host_unit_calendar():
 # ----------------------------------------------------------------------
 # streaming (constant-memory) path
 # ----------------------------------------------------------------------
-def test_stream_summary_matches_materialized_results():
-    trie, ann, execu, load, reqs, arrivals, lat_q = _serving_setup(5)
+# the per-request result columns: only the materialized (stream=False)
+# state carries them
+RESULT_COLUMNS = {"roc", "rsc", "rct", "rdn", "rad", "ru", "rpc"}
+
+
+@pytest.fixture
+def final_state(monkeypatch):
+    """The device state each compiled call's last epoch step returned,
+    under ``"st"`` (the step is wrapped to record it)."""
+    from repro.core import events_compiled
+
+    build, seen = events_compiled._build_step, {}
+
+    def recording(cfg):
+        step = build(cfg)
+
+        def recorded(st, cn, t_hi):
+            seen["st"] = step(st, cn, t_hi)
+            return seen["st"]
+        return recorded
+
+    monkeypatch.setattr(events_compiled, "_build_step", recording)
+    # the wrapper cannot be lowered again by `engine_scope_maps`
+    monkeypatch.setattr(events_compiled, "_ENGINE_CALLS", {})
+    return seen
+
+
+def _stream_scenario(name):
+    """One serving scenario per calendar and fault lane: processor
+    sharing, the token calendar, priority classes with preemption, and
+    engine outages with stage failures."""
+    trie, ann, execu, load, reqs, arrivals, lat_q = _serving_setup(
+        7 if name == "priority" else 5)
     obj = Objective("max_acc", lat_cap=lat_q)
     kw = dict(arrivals=arrivals, capacity=4, policy="dynamic_load_aware",
-              fleet_load=load, admission="feasibility")
+              admission="feasibility", fleet_load=load)
+    if name == "tokens":
+        engines = sorted({m.engine for m in trie.template.models})
+        kw.pop("fleet_load")
+        kw["work_model"] = TokenWorkModel(
+            engines={e: EngineTokenModel(e, t_weights_s=0.02, t_kv_s=0.002,
+                                         t_flop_s=0.001, kv_capacity=2.0,
+                                         prefill_tok_s=1e-4)
+                     for e in engines},
+            mean_service_s={e: 1.0 for e in engines},
+            stage_tokens=lambda q, d, m: (float(100 + 37 * q % 400),
+                                          float(20 + 13 * (q + d + m) % 40)))
+    elif name == "priority":
+        kw.update(capacity=3, admission="predictive", preempt=True,
+                  classes=sample_classes(len(reqs), (0.4, 0.6), seed=7),
+                  class_specs=(
+                      SLOClass("hi", deadline_s=lat_q * 0.75, weight=4.0),
+                      SLOClass("lo", deadline_s=None, weight=1.0)))
+    elif name == "chaos":
+        kw["faults"] = FaultSchedule(outages=((0, 1.0, 4.0),),
+                                     stage_failure_rate=0.3, seed=3,
+                                     max_retries=2)
+    return trie, ann, obj, reqs, execu, kw
+
+
+@pytest.mark.parametrize("scenario", ["ps", "tokens", "priority", "chaos"])
+def test_stream_summary_matches_materialized_results(scenario, final_state):
+    trie, ann, obj, reqs, execu, kw = _stream_scenario(scenario)
     res, stats = run_events_compiled(trie, ann, obj, reqs, execu, **kw)
+    full = final_state["st"]
     summary, sstats = run_events_compiled(trie, ann, obj, reqs, execu,
                                           stream=True, **kw)
+    # the stream state carries no per-request result columns
+    assert RESULT_COLUMNS <= set(full)
+    assert not RESULT_COLUMNS & set(final_state["st"])
+    if scenario == "priority":
+        assert stats.preemptions > 0
+    if scenario == "chaos":
+        assert stats.engine_outages == 1 and stats.stage_failures > 0
+    # bit for bit what the materialized run's state holds
+    assert summary["succeeded"] == int(full["nsc"]) == int(
+        np.sum(full["rsc"])) == sum(r.success for r in res)
+    assert summary["slo_violations"] == int(full["slo"]) == sum(
+        r.slo_violated for r in res)
+    assert summary["sketch"]["counts"] == np.asarray(full["hist"]).tolist()
+    for key, acc in (("latency", "lw"), ("cost", "cw")):
+        assert summary[key] == welford_finalize(
+            tuple(float(x) for x in full[acc]))
     served = [r for r in res if r.outcome == "served"]
+    assert served
     assert summary["n_requests"] == len(reqs)
     assert summary["served"] == len(served)
-    assert summary["succeeded"] == sum(r.success for r in res)
     assert summary["rejected"] == stats.rejected
     assert summary["shed"] == stats.shed
-    assert summary["slo_violations"] == sum(r.slo_violated for r in res)
+    assert summary["failed"] == stats.failed
     # Welford moments over the SERVED population, exact to rounding
     lats = np.array([r.total_lat for r in served])
     costs = np.array([r.total_cost for r in served])
@@ -133,11 +217,16 @@ def test_stream_summary_matches_materialized_results():
                                                        rel=1e-12)
     assert summary["latency"]["std"] == pytest.approx(lats.std(), rel=1e-9)
     assert summary["cost"]["mean"] == pytest.approx(costs.mean(), rel=1e-12)
-    # sketch quantiles: upper edge of the rank bin — at least the true
-    # order statistic, at most one log-spaced bin (~3.3%) above it
+    # sketch quantiles: the host sketch of the served latencies, which
+    # gives the upper edge of the rank bin — at least the order statistic
+    # min(floor(q n) + 1, n) it covers, at most one log-spaced bin (~3.3%)
+    # above it
+    sketch = QuantileSketch.log_spaced()
+    sketch.add(lats)
     for q, key in ((0.5, "latency_p50"), (0.95, "latency_p95"),
                    (0.99, "latency_p99")):
-        exact = float(np.quantile(lats, q, method="inverted_cdf"))
+        assert summary[key] == sketch.quantile(q)
+        exact = float(np.sort(lats)[min(int(q * len(lats)), len(lats) - 1)])
         assert summary[key] >= exact - 1e-9
         assert summary[key] <= max(exact * 1.04, 1.1e-3)
     # constant-memory guarantee: no O(n) per-request host lists
